@@ -208,6 +208,18 @@ def names():
     return sorted(_REGISTRY)
 
 
+def depth_cut(cfg: ArchConfig, n_layers: int) -> ArchConfig:
+    """The same architecture with only ``n_layers`` layers: every width
+    (d_model, heads, d_ff, vocab, ...) is kept, and the layer pattern is
+    cut in whole periods so each layer kind keeps its share."""
+    p = len(cfg.period)
+    if not (0 < n_layers <= cfg.n_layers and n_layers % p == 0):
+        raise ValueError(
+            f"{cfg.name}: --layers must be a multiple of the period length "
+            f"{p} in [{p}, {cfg.n_layers}], got {n_layers}")
+    return dataclasses.replace(cfg, n_layers=n_layers)
+
+
 def reduced(cfg: ArchConfig, *, n_layers: Optional[int] = None,
             d_model: int = 128, seq: int = 64) -> ArchConfig:
     """A tiny same-family variant for CPU smoke tests."""

@@ -76,8 +76,7 @@ def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
         return outs
 
     spec_params = jax.tree.map(lambda _: P(axis), stage_params)
-    from repro.distributed import sharding as _shd
-    return _shd.shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(spec_params, P()), out_specs=P(),
         check_vma=False)(stage_params, x_micro)

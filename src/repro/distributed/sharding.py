@@ -23,15 +23,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # jax < 0.5 ships it under experimental, with check_vma as check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map_compat
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-        return _shard_map_compat(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check_vma)
-
 
 def rules_for(mesh: Mesh, *, fsdp: bool = True, layout: str = "tp"
               ) -> Dict[str, Optional[Tuple[str, ...]]]:

@@ -7,7 +7,7 @@ bits for any width 3..16 — is stored as P byte-aligned *bit planes* per
 128-lane group (16 bytes per plane, Gecko-style), so an ``sfp-m2e4``
 tensor really occupies 7 bits/value plus the shared 8-bit group bases.
 
-The pack body is shared with kernels/sfp_pack.py (``_pack_body``: the
+The pack body is shared with kernels/sfp_pack.py (``ref.pack_words``: the
 fused Q(M, n) quantize + delta-exponent encode over one VMEM block); this
 module adds the word <-> plane transpose on either side, so quantize,
 container encode and plane packing all happen in a single pass over the
@@ -29,8 +29,7 @@ from jax.experimental import pallas as pl
 
 from repro.core import containers
 from repro.kernels import ref as kref
-from repro.kernels.sfp_pack import (DEFAULT_BLOCK_ROWS, _pack_body, _row_grid,
-                                    _to_rows)
+from repro.kernels.sfp_pack import DEFAULT_BLOCK_ROWS, _row_grid, _to_rows
 
 LANES = kref.GROUP  # 128
 
@@ -59,23 +58,22 @@ def vmem_estimate(*, fields: kref.PackFields,
 
 
 def _bitplane_pack_kernel(x_ref, plane_ref, base_ref, *, spec, fields):
-    word, base = _pack_body(x_ref[...], fields, spec)
+    word, base = kref.pack_words(x_ref[...], fields, spec)
     plane_ref[...] = kref.plane_pack_words(word, fields.payload_bits)
-    base_ref[...] = base
+    base_ref[...] = base.astype(jnp.uint8)
 
 
 def _bitplane_quantize_pack_kernel(n_ref, x_ref, plane_ref, base_ref, *,
                                    spec, fields):
-    word, base = _pack_body(x_ref[...], fields, spec, n=n_ref[0, 0])
+    word, base = kref.pack_words(x_ref[...], fields, spec, n=n_ref[0, 0])
     plane_ref[...] = kref.plane_pack_words(word, fields.payload_bits)
-    base_ref[...] = base
+    base_ref[...] = base.astype(jnp.uint8)
 
 
 def _bitplane_unpack_kernel(plane_ref, base_ref, o_ref, *, spec,
                             fields: kref.PackFields):
-    # Same decode body as the ref oracle and the flash-decode tiles
-    # (SWAR plane transpose + uint8 field machine where the geometry
-    # allows) — one definition, bit-exact everywhere.
+    # Same decode body as the ref oracle and the flash-decode tiles — one
+    # definition of the int32 plane expansion and field machine.
     o_ref[...] = kref.unpack_planes(plane_ref[...], base_ref[...], fields,
                                     spec)
 

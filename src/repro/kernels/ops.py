@@ -15,6 +15,7 @@ array-only so it can ride through lax.scan as the compressed stash.
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional
 
 import jax
@@ -212,29 +213,52 @@ def attention(q, k, v, *, causal=True, window=None, softcap=None,
 
     GQA is native in the kernel: the q-head group is folded into the query
     rows (``q_rep``), so the KH-headed K/V are streamed once per group —
-    no repeated-KV materialization in HBM.
+    no repeated-KV materialization in HBM. The kernel path is
+    differentiable: its VJP is the reference's (see ``_flash``).
     """
     b = backend()
     if b in ("pallas", "interpret") and prefix_len == 0 and q_offset == 0:
-        B, Sq, H, D = q.shape
-        KH = k.shape[2]
-        rep = H // KH
-        if rep > 1:
-            # (B, Sq, KH, rep, D) -> rows ordered (seq, group): row r of the
-            # folded query axis is seq r // rep, group member r % rep.
-            qg = q.reshape(B, Sq, KH, rep, D).transpose(0, 1, 3, 2, 4)
-            qg = qg.reshape(B, Sq * rep, KH, D)
-            o = _fa.flash_attention(qg, k, v, causal=causal, window=window,
-                                    softcap=softcap, q_rep=rep,
-                                    interpret=(b == "interpret"))
-            o = o.reshape(B, Sq, rep, KH, D).transpose(0, 1, 3, 2, 4)
-            return o.reshape(B, Sq, H, D)
-        return _fa.flash_attention(q, k, v, causal=causal, window=window,
-                                   softcap=softcap,
-                                   interpret=(b == "interpret"))
+        return _flash(q, k, v, causal, window, softcap, b == "interpret")
     return _ref.attention(q, k, v, causal=causal, window=window,
                           softcap=softcap, prefix_len=prefix_len,
                           q_offset=q_offset)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, window, softcap, interpret):
+    """Forward-only flash kernel with GQA folded into the query rows."""
+    B, Sq, H, D = q.shape
+    KH = k.shape[2]
+    rep = H // KH
+    if rep == 1:
+        return _fa.flash_attention(q, k, v, causal=causal, window=window,
+                                   softcap=softcap, interpret=interpret)
+    # (B, Sq, KH, rep, D) -> rows ordered (seq, group): row r of the
+    # folded query axis is seq r // rep, group member r % rep.
+    qg = q.reshape(B, Sq, KH, rep, D).transpose(0, 1, 3, 2, 4)
+    qg = qg.reshape(B, Sq * rep, KH, D)
+    o = _fa.flash_attention(qg, k, v, causal=causal, window=window,
+                            softcap=softcap, q_rep=rep, interpret=interpret)
+    o = o.reshape(B, Sq, rep, KH, D).transpose(0, 1, 3, 2, 4)
+    return o.reshape(B, Sq, H, D)
+
+
+def _flash_fwd(q, k, v, causal, window, softcap, interpret):
+    return _flash(q, k, v, causal, window, softcap, interpret), (q, k, v)
+
+
+def _flash_bwd(causal, window, softcap, interpret, res, g):
+    # The kernel computes ref.attention up to f32 rounding, so the
+    # reference's VJP (recomputed from q, k, v) is the kernel's gradient.
+    # It materializes (B, H, Sq, Sk) scores; training routes only short
+    # sequences here (models/attention.attention_train chunks long ones).
+    _, vjp = jax.vjp(functools.partial(_ref.attention, causal=causal,
+                                       window=window, softcap=softcap),
+                     *res)
+    return vjp(g)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def packed_flash_decode(q, k_packed: Packed, v_packed: Packed, pos, *,
@@ -246,7 +270,8 @@ def packed_flash_decode(q, k_packed: Packed, v_packed: Packed, pos, *,
     ``sfp_pack_nd`` layout — payload (B, L, KH*hd), bases (B, L, D//128).
     On pallas/interpret this is the fused decompress-attend kernel (the
     bf16 cache never materializes in HBM); on the ref backend it is the
-    unpack-then-attend oracle, the kernel's bit-exactness target.
+    unpack-then-attend oracle (the kernel matches it bit for bit in
+    interpret mode, to f32 rounding on a TPU).
     ``prefix_planes`` is the speculative draft read mode: only the leading
     P' payload bits of the same packed cache are expanded, decoded as the
     truncated geometry (``ref.prefix_fields``) — same blocks, fewer planes.

@@ -32,8 +32,9 @@ DMAs its physical block straight from the HBM pool. Same recurrence, same
 bit machine, same masks.
 
 Oracles: ``ref.packed_flash_decode`` / ``ref.paged_flash_decode``
-(unpack-then-attend with the same block recurrence) — bit-exact in
-interpret mode.
+(unpack-then-attend with the same block recurrence) — bit-exact against
+the jitted oracle in interpret mode. Compiled for a TPU, the f32 softmax
+accumulation runs in Mosaic's order, so results agree to f32 rounding.
 """
 from __future__ import annotations
 
@@ -43,6 +44,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import containers
 from repro.kernels import ref as kref
@@ -63,9 +65,9 @@ def vmem_estimate(*, fields: kref.PackFields, H: int, KH: int, hd: int,
     fuses are not charged — this is a budget model for the static
     contract check (``repro.analysis.vmem``), not an allocator.
 
-    The paged variant has the same window shapes (its block table and
-    positions are scalar-prefetch operands living in SMEM), so one model
-    covers both entry points.
+    Both entry points have the same window shapes (positions, and the
+    paged variant's block table, are scalar-prefetch operands living in
+    SMEM), so one model covers both.
     """
     D = KH * hd
     G = D // kref.GROUP
@@ -74,8 +76,7 @@ def vmem_estimate(*, fields: kref.PackFields, H: int, KH: int, hd: int,
     isz = jnp.dtype(dtype).itemsize
     psz = 1 if fields.dense else jnp.dtype(fields.payload_dtype).itemsize
     blocks = 2 * (
-        4                                    # pos (1, 1) int32
-        + KH * rep * hd * isz                # q block
+        KH * rep * hd * isz                  # q block
         + 2 * block_l * Dp * psz             # k/v payload blocks
         + 2 * block_l * G                    # k/v base blocks (uint8)
         + KH * rep * hd * isz                # out block
@@ -101,7 +102,7 @@ def _decode_kernel(pos_ref, q_ref, kp_ref, kb_ref, vp_ref, vb_ref, o_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    pos = pos_ref[0, 0]
+    pos = pos_ref[pl.program_id(0)]
 
     # Softmax-fused expansion: only this grid step's block_l-slot tile is
     # decompressed (ref.unpack_tile — the one inline-decompressor body both
@@ -189,33 +190,36 @@ def packed_flash_decode(q: jax.Array, k_payload: jax.Array,
     grid = (B, L // block_l)
 
     qg = q.reshape(B, KH, rep, hd)  # q head h shares kv head h // rep
-    pos2 = jnp.broadcast_to(
-        jnp.asarray(pos, jnp.int32).reshape(-1, 1), (B, 1))
+    pos1 = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
     scale = 1.0 / (hd ** 0.5)
 
-    out = pl.pallas_call(
-        functools.partial(_decode_kernel, block_l=block_l, L=L, KH=KH,
-                          hd=hd, window=window, softcap=softcap, scale=scale,
-                          fields=fields, spec=spec,
-                          prefix_planes=prefix_planes),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,  # per-row decode positions, in SMEM
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, j: (b, 0)),          # per-row pos
-            pl.BlockSpec((1, KH, rep, hd), lambda b, j: (b, 0, 0, 0)),
-            pl.BlockSpec((1, block_l, Dp), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_l, G), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_l, Dp), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_l, G), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, KH, rep, hd), lambda b, j, pos: (b, 0, 0, 0)),
+            pl.BlockSpec((1, block_l, Dp), lambda b, j, pos: (b, j, 0)),
+            pl.BlockSpec((1, block_l, G), lambda b, j, pos: (b, j, 0)),
+            pl.BlockSpec((1, block_l, Dp), lambda b, j, pos: (b, j, 0)),
+            pl.BlockSpec((1, block_l, G), lambda b, j, pos: (b, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, KH, rep, hd), lambda b, j: (b, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, KH, rep, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, KH, rep, hd),
+                               lambda b, j, pos: (b, 0, 0, 0)),
         scratch_shapes=[
             _vmem_scratch((KH, rep, 1)),
             _vmem_scratch((KH, rep, 1)),
             _vmem_scratch((KH, rep, hd)),
         ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, block_l=block_l, L=L, KH=KH,
+                          hd=hd, window=window, softcap=softcap, scale=scale,
+                          fields=fields, spec=spec,
+                          prefix_planes=prefix_planes),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KH, rep, hd), q.dtype),
         interpret=interpret,
-    )(pos2, qg, k_payload, k_bases, v_payload, v_bases)
+    )(pos1, qg, k_payload, k_bases, v_payload, v_bases)
     return out.reshape(B, 1, H, hd)
 
 
@@ -303,10 +307,9 @@ def paged_flash_decode(q: jax.Array, k_payload: jax.Array,
     no-ops. Global attention only (local ring buffers are window-bounded
     and stay per-slot contiguous). Returns (B, 1, H, hd) in q's dtype.
 
-    Oracle: ``ref.paged_flash_decode`` — bit-exact in interpret mode.
+    Oracle: ``ref.paged_flash_decode`` — bit-exact in interpret mode,
+    equal to f32 rounding when compiled for a TPU.
     """
-    from jax.experimental.pallas import tpu as pltpu
-
     interpret = kref.default_interpret(interpret)
 
     B, one, H, hd = q.shape

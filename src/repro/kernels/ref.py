@@ -127,20 +127,43 @@ def _to_rows(x: jax.Array) -> jax.Array:
     return flat.reshape(-1, GROUP)
 
 
-def _pack_words(x: jax.Array, f: PackFields, spec: containers.FloatSpec,
+# The bit machine below is written once for the jnp oracles and the Pallas
+# kernels (which call these functions on their VMEM tiles). It works in
+# int32 lanes only: Mosaic cannot lower shifts of 8/16-bit vectors or
+# bitcasts that change the element width, so narrow containers are widened
+# on load and narrowed on store, never shifted in place.
+
+
+def _float_to_bits(x: jax.Array, spec: containers.FloatSpec) -> jax.Array:
+    """Float bits as int32 (16-bit floats zero-extended)."""
+    if spec.total_bits == 32:
+        return jax.lax.bitcast_convert_type(x, jnp.int32)
+    return jax.lax.bitcast_convert_type(x, spec.int_dtype).astype(jnp.int32)
+
+
+def _bits_to_float(bits: jax.Array, spec: containers.FloatSpec) -> jax.Array:
+    """Inverse of ``_float_to_bits``: int32 bits -> ``spec.dtype``."""
+    if spec.total_bits == 32:
+        return jax.lax.bitcast_convert_type(bits, spec.dtype)
+    return jax.lax.bitcast_convert_type(bits.astype(spec.int_dtype),
+                                        spec.dtype)
+
+
+def pack_words(x: jax.Array, f: PackFields, spec: containers.FloatSpec,
                 n=None) -> Tuple[jax.Array, jax.Array]:
-    """Shared pack body over the last (128-lane) axis.
+    """Shared pack body over the last (128-lane) axis: floats -> (int32
+    payload words, int32 max-exponent bases with a kept unit last axis).
 
     ``n`` (optional, traced ok) fuses Q(M, n) mantissa truncation into the
     same pass — the quantize+pack fusion of the hardware compressor.
     """
-    sign, e, man = containers.split_fields(x)
-    sign = sign.astype(jnp.int32)
-    e = e.astype(jnp.int32)
-    man = man.astype(jnp.int32)
+    u = _float_to_bits(x, spec)
+    sign = (u >> spec.sign_shift) & 1
+    e = (u >> spec.exp_shift) & spec.exp_mask
+    man = u & spec.man_mask
     if n is not None:
-        keep = containers._mantissa_keep_mask(n, spec).astype(jnp.int32)
-        man = man & keep
+        drop = spec.man_bits - jnp.clip(n, 0, spec.man_bits)
+        man = man & (spec.man_mask ^ ((1 << drop) - 1))
 
     base = jnp.max(e, axis=-1, keepdims=True)  # max-exponent base: deltas >= 0
     dexp = base - e
@@ -152,24 +175,22 @@ def _pack_words(x: jax.Array, f: PackFields, spec: containers.FloatSpec,
 
     word = ((sign << f.sign_shift) | (dexp << f.dexp_shift)
             | (man_top << f.man_shift))
-    return word.astype(f.word_dtype), base
+    return word, base
 
 
-def _unpack_words(p: jax.Array, base: jax.Array, f: PackFields,
+def unpack_words(p: jax.Array, base: jax.Array, f: PackFields,
                   spec: containers.FloatSpec) -> jax.Array:
+    """Payload words (any int dtype) + broadcastable bases -> floats of
+    ``spec.dtype``. (dexp == max, man == 0) decodes to +0."""
     p = p.astype(jnp.int32)
     sign = (p >> f.sign_shift) & 1
     dexp = (p >> f.dexp_shift) & f.dexp_max
     man_top = (p >> f.man_shift) & ((1 << f.man_keep) - 1)
     e = jnp.maximum(base.astype(jnp.int32) - dexp, 0)
-    man = man_top << (spec.man_bits - f.man_keep)
     flush = (dexp == f.dexp_max) & (man_top == 0)
-    e = jnp.where(flush, 0, e)
-    man = jnp.where(flush, 0, man)
-    sign = jnp.where(flush, 0, sign)
-    return containers.combine_fields(
-        sign.astype(spec.int_dtype), e.astype(spec.int_dtype),
-        man.astype(spec.int_dtype), spec)
+    bits = ((sign << spec.sign_shift) | (e << spec.exp_shift)
+            | (man_top << (spec.man_bits - f.man_keep)))
+    return _bits_to_float(jnp.where(flush, 0, bits), spec)
 
 
 def sfp_pack(x: jax.Array, fields: PackFields, n=None):
@@ -180,8 +201,8 @@ def sfp_pack(x: jax.Array, fields: PackFields, n=None):
     fuses mantissa truncation Q(M, n) into the same pass.
     """
     spec = containers.spec_for(x)
-    payload, base = _pack_words(_to_rows(x), fields, spec, n)
-    return payload, base.astype(jnp.uint8)
+    payload, base = pack_words(_to_rows(x), fields, spec, n)
+    return payload.astype(fields.word_dtype), base.astype(jnp.uint8)
 
 
 def sfp_pack_nd(x: jax.Array, fields: PackFields, n=None):
@@ -195,8 +216,9 @@ def sfp_pack_nd(x: jax.Array, fields: PackFields, n=None):
     assert D % GROUP == 0, (x.shape,)
     spec = containers.spec_for(x)
     xg = x.reshape(*x.shape[:-1], D // GROUP, GROUP)
-    payload, base = _pack_words(xg, fields, spec, n)
-    return payload.reshape(x.shape), base[..., 0].astype(jnp.uint8)
+    payload, base = pack_words(xg, fields, spec, n)
+    return (payload.astype(fields.word_dtype).reshape(x.shape),
+            base[..., 0].astype(jnp.uint8))
 
 
 def sfp_unpack_nd(payload: jax.Array, bases: jax.Array, dtype,
@@ -204,14 +226,14 @@ def sfp_unpack_nd(payload: jax.Array, bases: jax.Array, dtype,
     spec = containers.spec_for(jnp.dtype(dtype))
     D = payload.shape[-1]
     p = payload.reshape(*payload.shape[:-1], D // GROUP, GROUP)
-    out = _unpack_words(p, bases.astype(jnp.int32)[..., None], fields, spec)
+    out = unpack_words(p, bases.astype(jnp.int32)[..., None], fields, spec)
     return out.reshape(payload.shape)
 
 
 def sfp_unpack(payload: jax.Array, bases: jax.Array, shape: tuple,
                dtype, fields: PackFields) -> jax.Array:
     spec = containers.spec_for(jnp.dtype(dtype))
-    out = _unpack_words(payload, bases, fields, spec)
+    out = unpack_words(payload, bases, fields, spec)
     n = 1
     for s in shape:
         n *= s
@@ -231,147 +253,63 @@ def sfp_unpack(payload: jax.Array, bases: jax.Array, shape: tuple,
 # ---------------------------------------------------------------------------
 
 
-def _reg_transpose8(rows):
-    """SWAR 8x8 bit-matrix transpose (Hacker's Delight delta-swaps) with
-    the 8 matrix rows in separate uint32 arrays.
-
-    Each uint32 element carries 4 *independent* byte-matrices side by side
-    (byte c of ``rows[p]`` is row p of matrix c); the odd/even bit masks
-    keep every delta-swap byte-local, so one pass transposes 4 matrices at
-    once. This is the whole plane <-> word conversion: 12 masked swaps per
-    32 payload bytes instead of one gather-shift-accumulate per *bit*, so
-    the work scales with plane bytes, not bits x lanes.
-    """
-    x = list(rows)
-    M1 = jnp.uint32(0xAAAAAAAA)
-    M2 = jnp.uint32(0xCCCCCCCC)
-    M4 = jnp.uint32(0xF0F0F0F0)
-    for i in (0, 2, 4, 6):
-        a, b = x[i], x[i + 1]
-        t = (a ^ (b << 1)) & M1
-        x[i], x[i + 1] = a ^ t, b ^ (t >> 1)
-    for i in (0, 1, 4, 5):
-        a, b = x[i], x[i + 2]
-        t = (a ^ (b << 2)) & M2
-        x[i], x[i + 2] = a ^ t, b ^ (t >> 2)
-    for i in (0, 1, 2, 3):
-        a, b = x[i], x[i + 4]
-        t = (a ^ (b << 4)) & M4
-        x[i], x[i + 4] = a ^ t, b ^ (t >> 4)
-    return x
-
-
-def _u32_to_bytes(w: jax.Array) -> jax.Array:
-    """(..., n) uint32 -> (..., 4n) uint8, little-endian."""
-    out = jax.lax.bitcast_convert_type(w[..., None], jnp.uint8)
-    return out.reshape(*w.shape[:-1], w.shape[-1] * 4)
+def _lane_bit(shape) -> jax.Array:
+    """Bit index j = lane % 8 of each lane's bit inside its plane byte."""
+    return jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1) % 8
 
 
 def plane_pack_words(words: jax.Array, payload_bits: int) -> jax.Array:
     """Transpose payload words (..., 128) into bit planes (..., P*16) u8.
 
-    Byte-granular: each block of <= 8 planes is one register-SWAR 8x8
-    bit-matrix transpose over the group's 16 byte columns (bit j of plane
-    byte i <-> bit i of word byte j for lanes 8i..8i+7).
+    Plane p byte i is the sum over j of bit p of lane 8i+j's word times
+    2^j. The sum over each run of 8 lanes is a matmul against a 0/1
+    (128, 16) matrix: inputs 0 or 2^j <= 128 are exact in bf16 and the
+    byte sums (<= 255) are exact in the f32 accumulator, on the MXU and
+    on the CPU alike.
     """
-    P = payload_bits
-    lead = words.shape[:-1]
-    w = words.astype(jnp.int32) & ((1 << P) - 1)
-    planes = []
-    for lo in range(0, P, 8):
-        byt = ((w >> lo) & 0xFF).astype(jnp.uint8)
-        byt = byt.reshape(*lead, PLANE_BYTES, 8)
-        rows = [jax.lax.bitcast_convert_type(
-            byt[..., j].reshape(*lead, 4, 4), jnp.uint32)
-            for j in range(8)]                     # row j = lane-j bytes
-        x = _reg_transpose8(rows)                  # x[p] = plane lo+p bytes
-        n = min(8, P - lo)
-        pl = jnp.stack(x[:n], axis=-2)             # (..., n, 4) u32
-        planes.append(_u32_to_bytes(pl).reshape(*lead, n * PLANE_BYTES))
-    return (jnp.concatenate(planes, axis=-1) if len(planes) > 1
-            else planes[0])
+    w = words.astype(jnp.int32)
+    j = _lane_bit(w.shape)
+    seg = (jax.lax.broadcasted_iota(jnp.int32, (GROUP, PLANE_BYTES), 0) // 8
+           == jax.lax.broadcasted_iota(jnp.int32, (GROUP, PLANE_BYTES), 1)
+           ).astype(jnp.bfloat16)
+    planes = [jnp.dot((((w >> p) & 1) << j).astype(jnp.bfloat16), seg,
+                      preferred_element_type=jnp.float32)
+              for p in range(payload_bits)]
+    out = jnp.concatenate(planes, axis=-1) if payload_bits > 1 else planes[0]
+    return out.astype(jnp.int32).astype(jnp.uint8)
 
 
 def plane_unpack_words(planes: jax.Array, payload_bits: int) -> jax.Array:
     """Invert plane_pack_words: (..., P*16) uint8 -> (..., 128) int32.
 
-    Same SWAR transpose as the pack direction (the 8x8 bit transpose is an
-    involution up to row/column naming): byte i of <= 8 stacked planes
-    turns into the payload bytes of lanes 8i..8i+7 in 18 word ops — no
-    per-bit gather, so expansion cost tracks the plane bytes actually read.
+    One matmul against a 0/1 selection matrix repeats each plane byte over
+    the 8 lanes it covers (bytes <= 255 are exact in bf16, and each output
+    sums exactly one of them), so lane 8i+j of plane p's 128-lane block
+    holds byte i; bit j is then shifted out.
     """
-    bs = _plane_unpack_bytes(planes, payload_bits)
-    w = bs[0].astype(jnp.int32)
-    if len(bs) > 1:
-        w = w | (bs[1].astype(jnp.int32) << 8)
-    return w
-
-
-def _plane_unpack_bytes(planes: jax.Array, payload_bits: int):
-    """SWAR plane expansion to payload *bytes*: (..., P*16) uint8 planes ->
-    [low bytes] or [low, high bytes], each (..., 128) uint8 — the word is
-    never widened here, so sub-byte consumers can stay in uint8. Missing
-    planes of a partial block are zero registers, not padded memory."""
-    P = payload_bits
     lead = planes.shape[:-1]
-    u = jax.lax.bitcast_convert_type(
-        planes.reshape(*lead, P, 4, 4), jnp.uint32)     # (..., P, 4)
-    out_bytes = []
-    for lo in range(0, P, 8):
-        n = min(8, P - lo)
-        zero = jnp.zeros((*lead, 4), jnp.uint32)
-        rows = [u[..., lo + r, :] if r < n else zero for r in range(8)]
-        y = _reg_transpose8(rows)                  # y[j] byte i = lane 8i+j
-        out = jnp.stack([_u32_to_bytes(yj) for yj in y], axis=-1)
-        out_bytes.append(out.reshape(*lead, GROUP))
-    return out_bytes
-
-
-def _unpack_bytes_u8(p: jax.Array, base: jax.Array, f: PackFields,
-                     spec: containers.FloatSpec) -> jax.Array:
-    """uint8-domain twin of ``_unpack_words`` for sub-byte payloads.
-
-    When the payload fits one byte and the target float's exponent and
-    mantissa each fit a byte (bf16: 8/7), every intermediate — fields,
-    rebuilt exponent, shifted mantissa — stays uint8; nothing widens until
-    ``combine_fields`` builds the 16-bit output word. On the single-core
-    ref backend this shaves the int32 widen pass, the largest single cost
-    of the dense decode path after the SWAR transpose itself.
-    """
-    sign = (p >> jnp.uint8(f.sign_shift)) & jnp.uint8(1)
-    dexp = (p >> jnp.uint8(f.dexp_shift)) & jnp.uint8(f.dexp_max)
-    man_top = p & jnp.uint8((1 << f.man_keep) - 1)
-    if f.man_shift:
-        man_top = (p >> jnp.uint8(f.man_shift)) & jnp.uint8(
-            (1 << f.man_keep) - 1)
-    # max-then-subtract clamps base - dexp at zero without a select; the
-    # flush-to-zero test (dexp == max AND man == 0) is one masked compare
-    # on the raw payload byte.
-    e = jnp.maximum(base.astype(jnp.uint8), dexp) - dexp
-    fl_mask = jnp.uint8((f.dexp_max << f.dexp_shift)
-                        | (((1 << f.man_keep) - 1) << f.man_shift))
-    keep = (p & fl_mask) != jnp.uint8(f.dexp_max << f.dexp_shift)
-    w = ((sign.astype(spec.int_dtype) << spec.sign_shift)
-         | (e.astype(spec.int_dtype) << spec.exp_shift)
-         | (man_top.astype(spec.int_dtype)
-            << (spec.man_bits - f.man_keep)))
-    w = jnp.where(keep, w, jnp.zeros_like(w))
-    return containers.bitcast_to_float(w, spec)
+    n, cols = planes.shape[-1], payload_bits * GROUP
+    k = jax.lax.broadcasted_iota(jnp.int32, (n, cols), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (n, cols), 1)
+    pick = (k == (c // GROUP) * PLANE_BYTES
+            + (c % GROUP) // 8).astype(jnp.bfloat16)
+    x = planes.astype(jnp.int32).astype(jnp.float32).astype(jnp.bfloat16)
+    byte = jnp.dot(x, pick,
+                   preferred_element_type=jnp.float32).astype(jnp.int32)
+    j = _lane_bit((*lead, GROUP))
+    w = jnp.zeros((*lead, GROUP), jnp.int32)
+    for p in range(payload_bits):
+        w = w | (((byte[..., p * GROUP:(p + 1) * GROUP] >> j) & 1) << p)
+    return w
 
 
 def unpack_planes(planes: jax.Array, bases: jax.Array, fields: PackFields,
                   spec: containers.FloatSpec) -> jax.Array:
     """Dense plane decode: (..., P*16) planes + broadcastable bases ->
-    (..., 128) floats. One definition for the ref oracles, the Pallas
-    unpack kernel and the flash-decode tiles; picks the uint8 fast path
-    whenever the geometry allows (sub-byte payload, byte-sized float
-    fields), falling back to the int32 word machine otherwise."""
-    if (fields.payload_bits <= 8 and spec.exp_bits <= 8
-            and spec.man_bits <= 8):
-        (p,) = _plane_unpack_bytes(planes, fields.payload_bits)
-        return _unpack_bytes_u8(p, bases, fields, spec)
-    words = plane_unpack_words(planes, fields.payload_bits)
-    return _unpack_words(words, bases.astype(jnp.int32), fields, spec)
+    (..., 128) floats. One definition for the ref oracles and the Pallas
+    unpack kernel."""
+    return unpack_words(plane_unpack_words(planes, fields.payload_bits),
+                         bases, fields, spec)
 
 
 def prefix_fields(fields: PackFields, prefix_planes: int) -> PackFields:
@@ -406,10 +344,8 @@ def prefix_plane_view(payload: jax.Array, fields: PackFields,
     """Slice a dense group payload (..., P*16) to its leading-plane prefix
     (..., P'*16): the last P' planes in storage order (planes are stored
     LSB-first, and the prefix keeps the *high* bits of the word)."""
-    P, Pp = fields.payload_bits, int(prefix_planes)
-    lead = payload.shape[:-1]
-    pl = payload.reshape(*lead, P, PLANE_BYTES)
-    return pl[..., P - Pp:, :].reshape(*lead, Pp * PLANE_BYTES)
+    drop = fields.payload_bits - int(prefix_planes)
+    return payload[..., drop * PLANE_BYTES:]
 
 
 def unpack_tile(payload: jax.Array, bases: jax.Array, fields: PackFields,
@@ -421,39 +357,31 @@ def unpack_tile(payload: jax.Array, bases: jax.Array, fields: PackFields,
     bit planes — and ``bases`` (rows, G) expand to (rows, KH, hd) float32.
     This is the body both flash-decode kernels run on each KV tile inside
     the online-softmax loop: only the ``rows`` (= block_l) slots being
-    consumed are ever expanded, in VMEM, immediately before the dot —
-    dense geometries go through the SWAR plane transpose first.
+    consumed are ever expanded, in VMEM, immediately before the dot. Each
+    128-lane group is a static lane slice of the tile (no lane reshapes,
+    which Mosaic cannot lower at plane-byte granularity).
 
     ``prefix_planes`` selects the speculative *draft* read mode: only the
     leading P' bits of each payload word are expanded, decoded as the
-    truncated geometry (``prefix_fields``). Dense geometries slice the
-    plane bytes before the SWAR transpose, so the expansion work (and, on
-    a DMA'd backend, the bytes moved) shrinks with P'; fixed-lane words
-    shift in place (same bytes, same truncated semantics).
+    truncated geometry (``prefix_fields``). Dense geometries skip the low
+    planes, so the expansion work shrinks with P'; fixed-lane words shift
+    in place (same bytes, same truncated semantics).
     """
     G = (KH * hd) // GROUP
-    if prefix_planes is not None and prefix_planes != fields.payload_bits:
-        nf = prefix_fields(fields, prefix_planes)
+    f = (fields if prefix_planes is None
+         else prefix_fields(fields, prefix_planes))
+    drop = fields.payload_bits - f.payload_bits
+    width = fields.group_payload_bytes if fields.dense else GROUP
+    out = []
+    for g in range(G):
+        p = payload[:, g * width:(g + 1) * width]
         if fields.dense:
-            planes = prefix_plane_view(
-                payload.reshape(rows, G, fields.group_payload_bytes),
-                fields, prefix_planes)
-            x = unpack_planes(planes, bases.reshape(rows, G, 1), nf, spec)
+            words = plane_unpack_words(
+                prefix_plane_view(p, fields, f.payload_bits), f.payload_bits)
         else:
-            drop = fields.payload_bits - nf.payload_bits
-            p = payload.astype(jnp.int32).reshape(rows, G, GROUP) >> drop
-            x = _unpack_words(p,
-                              bases.astype(jnp.int32).reshape(rows, G, 1),
-                              nf, spec)
-        return x.reshape(rows, KH, hd).astype(jnp.float32)
-    if fields.dense:
-        x = unpack_planes(
-            payload.reshape(rows, G, fields.group_payload_bytes),
-            bases.reshape(rows, G, 1), fields, spec)
-    else:
-        p = payload.astype(jnp.int32).reshape(rows, G, GROUP)
-        x = _unpack_words(p, bases.astype(jnp.int32).reshape(rows, G, 1),
-                          fields, spec)
+            words = p.astype(jnp.int32) >> drop
+        out.append(unpack_words(words, bases[:, g:g + 1], f, spec))
+    x = jnp.concatenate(out, axis=-1) if G > 1 else out[0]
     return x.reshape(rows, KH, hd).astype(jnp.float32)
 
 
@@ -465,7 +393,7 @@ def bitplane_pack(x: jax.Array, fields: PackFields, n=None):
     128-lane groups of the flattened tensor, zero-padded at the tail.
     """
     spec = containers.spec_for(x)
-    words, base = _pack_words(_to_rows(x), fields, spec, n)
+    words, base = pack_words(_to_rows(x), fields, spec, n)
     return plane_pack_words(words, fields.payload_bits), base.astype(jnp.uint8)
 
 
@@ -491,7 +419,7 @@ def bitplane_pack_nd(x: jax.Array, fields: PackFields, n=None):
     assert D % GROUP == 0, (x.shape,)
     spec = containers.spec_for(x)
     xg = x.reshape(*x.shape[:-1], D // GROUP, GROUP)
-    words, base = _pack_words(xg, fields, spec, n)
+    words, base = pack_words(xg, fields, spec, n)
     planes = plane_pack_words(words, fields.payload_bits)
     return (planes.reshape(*x.shape[:-1], fields.nd_payload_cols(D)),
             base[..., 0].astype(jnp.uint8))
@@ -621,7 +549,7 @@ def packed_flash_decode(q: jax.Array, k_payload: jax.Array,
     """Unpack-then-attend decode oracle for kernels/packed_flash_decode.py.
 
     Decompresses the whole packed cache (same bit logic as the kernel:
-    ``_unpack_words``) and attends the single query token with the same
+    ``unpack_words``) and attends the single query token with the same
     online-softmax block recurrence over ``block_l``-slot KV blocks, so
     the Pallas kernel validates bit-for-bit in interpret mode.
 

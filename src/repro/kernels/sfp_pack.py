@@ -44,8 +44,8 @@ def vmem_estimate(*, fields: kref.PackFields,
     """Static per-grid-step VMEM footprint model, in bytes.
 
     Double-buffered in/out block windows plus the int32 working tiles of
-    ``_pack_body`` (bitcast words, exponent/mantissa fields, packed word —
-    modeled as four live (block_rows, 128) int32 tiles; the unpack
+    ``ref.pack_words`` (bitcast words, exponent/mantissa fields, packed
+    word — modeled as four live (block_rows, 128) int32 tiles; the unpack
     direction is bounded by the same count). Budget model for
     ``repro.analysis.vmem``, not an allocator.
     """
@@ -62,61 +62,23 @@ def vmem_estimate(*, fields: kref.PackFields,
     return blocks + temps
 
 
-def _pack_body(x, fields: kref.PackFields, spec, n=None):
-    """Shared kernel body: (block, 128) floats -> (payload, base) words.
-
-    ``n`` (optional traced scalar) fuses Q(M, n) into the same pass.
-    """
-    u = jax.lax.bitcast_convert_type(x, spec.int_dtype).astype(jnp.int32)
-    sign = (u >> spec.sign_shift) & 1
-    e = (u >> spec.exp_shift) & spec.exp_mask
-    man = u & spec.man_mask
-    if n is not None:
-        nn = jnp.clip(n, 0, spec.man_bits)
-        drop = spec.man_bits - nn
-        man = man & (spec.man_mask ^ ((1 << drop) - 1))
-
-    base = jnp.max(e, axis=-1, keepdims=True)
-    dexp = base - e
-    man_top = man >> (spec.man_bits - fields.man_keep)
-    flush = (e == 0) | (dexp > fields.dexp_max)
-    dexp = jnp.where(flush, fields.dexp_max, jnp.minimum(dexp,
-                                                         fields.dexp_max))
-    man_top = jnp.where(flush, 0, man_top)
-    sign = jnp.where(e == 0, 0, sign)
-
-    word = ((sign << fields.sign_shift) | (dexp << fields.dexp_shift)
-            | (man_top << fields.man_shift))
-    return word.astype(fields.word_dtype), base.astype(jnp.uint8)
-
-
 def _pack_kernel(x_ref, payload_ref, base_ref, *, spec, fields):
-    payload_ref[...], base_ref[...] = _pack_body(x_ref[...], fields, spec)
+    word, base = kref.pack_words(x_ref[...], fields, spec)
+    payload_ref[...] = word.astype(payload_ref.dtype)
+    base_ref[...] = base.astype(jnp.uint8)
 
 
 def _quantize_pack_kernel(n_ref, x_ref, payload_ref, base_ref, *, spec,
                           fields):
-    payload_ref[...], base_ref[...] = _pack_body(
-        x_ref[...], fields, spec, n=n_ref[0, 0])
+    word, base = kref.pack_words(x_ref[...], fields, spec, n=n_ref[0, 0])
+    payload_ref[...] = word.astype(payload_ref.dtype)
+    base_ref[...] = base.astype(jnp.uint8)
 
 
 def _unpack_kernel(payload_ref, base_ref, o_ref, *, spec,
                    fields: kref.PackFields):
-    p = payload_ref[...].astype(jnp.int32)
-    sign = (p >> fields.sign_shift) & 1
-    dexp = (p >> fields.dexp_shift) & fields.dexp_max
-    man_top = (p >> fields.man_shift) & ((1 << fields.man_keep) - 1)
-    base = base_ref[...].astype(jnp.int32)
-    e = jnp.maximum(base - dexp, 0)
-    man = man_top << (spec.man_bits - fields.man_keep)
-    flush = (dexp == fields.dexp_max) & (man_top == 0)
-    e = jnp.where(flush, 0, e)
-    man = jnp.where(flush, 0, man)
-    sign = jnp.where(flush, 0, sign)
-    word = (
-        (sign << spec.sign_shift) | (e << spec.exp_shift) | man
-    ).astype(spec.int_dtype)
-    o_ref[...] = jax.lax.bitcast_convert_type(word, spec.dtype)
+    o_ref[...] = kref.unpack_words(payload_ref[...], base_ref[...], fields,
+                                    spec)
 
 
 def _to_rows(x: jax.Array) -> Tuple[jax.Array, int]:

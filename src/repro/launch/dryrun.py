@@ -202,8 +202,6 @@ def run_cell(arch_name: str, shape_name: str, mesh_kind: str,
 
         try:
             ca = compiled.cost_analysis()
-            if isinstance(ca, list):  # older jax: one dict per device
-                ca = ca[0]
             record["cost_analysis"] = {
                 k: float(v) for k, v in ca.items()
                 if isinstance(v, (int, float)) and k in

@@ -45,30 +45,40 @@ import numpy as np
 
 from repro import configs
 from repro import obs as obs_mod
-from repro.configs.base import reduced
+from repro.configs.base import depth_cut, reduced
 from repro.launch.args import container_name
+from repro.launch.cache import enable_compile_cache
 from repro.models.model import DecoderModel
 from repro.serve import engine, faults, precision
 from repro.serve.scheduler import Request, Scheduler
 
 
-def _build_model(args):
+def build_model(args, params=None):
+    """(cfg, model, params, container) for the serve flags; ``params``
+    reuses weights already built for the same --arch/--preset/--layers
+    (the KV container does not change them)."""
     cfg = configs.get(args.arch)
     if args.preset == "tiny":
         cfg = reduced(cfg)
     elif args.preset == "small":
         cfg = reduced(cfg, n_layers=max(2 * len(cfg.period), 4), d_model=256)
+    if args.layers:
+        cfg = depth_cut(cfg, args.layers)
     container = args.kv_container
     if args.policy_ckpt:
         container = precision.container_from_checkpoint(args.policy_ckpt)
         print(f"policy-aware container from {args.policy_ckpt}: {container}")
     model = DecoderModel(cfg, kv_container=container)
-    params = model.init(jax.random.PRNGKey(args.seed))
+    if params is None:
+        # Jitted, the f32 normal draws fuse into the bf16 weights instead
+        # of each materializing in f32 (a 2-layer published-width mistral
+        # peaks at 9.3 GB on the device this way).
+        params = jax.jit(model.init)(jax.random.PRNGKey(args.seed))
     return cfg, model, params, container
 
 
 def run_batch(args) -> None:
-    cfg, model, params, container = _build_model(args)
+    cfg, model, params, container = build_model(args)
     prompt = jax.random.randint(jax.random.PRNGKey(args.seed + 1),
                                 (args.batch, args.prompt_len), 0, cfg.vocab)
     cond = (jnp.zeros((args.batch, cfg.prefix_tokens, cfg.d_model),
@@ -108,8 +118,12 @@ def make_trace(args, vocab: int):
     return reqs
 
 
-def run_trace(args) -> None:
-    cfg, model, params, container = _build_model(args)
+def run_trace(args, built=None, on_step=None):
+    """Serve the --trace workload; prints and returns the report dict
+    together with the Scheduler (per-request outcomes in ``results``).
+    ``built`` is ``build_model``'s tuple (built from ``args`` if None);
+    ``on_step(step, sched)`` runs before each scheduler step."""
+    cfg, model, params, container = built or build_model(args)
     if container is None:
         raise SystemExit("--trace needs a packed cache: pass --kv-container "
                          "(or --policy-ckpt)")
@@ -155,6 +169,8 @@ def run_trace(args) -> None:
                 prof["on"] = False
         if hook is not None:
             hook(i)
+        if on_step is not None:
+            on_step(i, sched)
 
     # Virtual clock: admission sees arrivals as wall-clock-free step time
     # (one scheduler step advances it by --step-dt), so the same trace
@@ -229,6 +245,7 @@ def run_trace(args) -> None:
             {int(uid): [int(t) for t in toks] for uid, toks in out.items()},
             sort_keys=True))
     print(json.dumps(report, indent=2))
+    return report, sched
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -237,6 +254,9 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--preset", default="tiny", choices=["tiny", "small",
                                                          "full"])
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--layers", type=int, default=None,
+                    help="keep every width and cut the depth to N layers "
+                    "(whole periods; configs.base.depth_cut)")
     ap.add_argument("--kv-container", default=None, type=container_name,
                     help="registry codec for the packed KV cache (sfp8, "
                     "sfp16, dense sfp-m2e4, ...); None = raw bf16 cache")
@@ -331,7 +351,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main():
     args = build_parser().parse_args()
-
+    enable_compile_cache()
     if args.trace:
         run_trace(args)
     else:

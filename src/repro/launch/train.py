@@ -6,8 +6,8 @@
 ``--policy`` takes any registered precision policy (none, static, qm, qe,
 bitchop, bitwave) or a '+'-composition such as ``qm+qe`` (learn mantissa
 AND exponent bitlengths in one run). Presets scale the assigned configs
-down for the CPU environment; on real hardware drop --preset and pass
---mesh to shard across the fleet. The loop is fault-tolerant: it
+down for the CPU environment; ``--preset full`` keeps the published
+widths, and ``--layers N`` cuts only the depth. The loop is fault-tolerant: it
 checkpoints every --ckpt-every steps (recording the policy in the
 manifest) and restores+continues on step failure. The final report
 includes the modeled stash footprint under the learned/adapted decisions —
@@ -25,8 +25,9 @@ import numpy as np
 
 from repro import codecs, configs, policies
 from repro import obs as obs_mod
-from repro.configs.base import reduced
+from repro.configs.base import depth_cut, reduced
 from repro.launch.args import container_name, policy_name
+from repro.launch.cache import enable_compile_cache
 from repro.data import pipeline, synthetic
 from repro.models.model import DecoderModel
 from repro.optim import adamw
@@ -67,6 +68,8 @@ def build(args):
         batch, seq = 8, 128
     else:
         batch, seq = args.batch, args.seq
+    if args.layers:
+        cfg = depth_cut(cfg, args.layers)
 
     policy = build_policy(args)
     model = DecoderModel(cfg, policy)
@@ -95,6 +98,9 @@ def build_parser() -> argparse.ArgumentParser:
                     help="stash codec: any registered name "
                          f"({'/'.join(codecs.names())}) or a parametric "
                          "dense geometry like sfp-m2e4")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="keep every width and cut the depth to N layers "
+                         "(whole periods; configs.base.depth_cut)")
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -145,7 +151,7 @@ def main():
     # Container/policy typos fail in the usage message: both flags carry
     # registry-backed argparse validators (launch/args.py).
     args = build_parser().parse_args()
-
+    enable_compile_cache()
     cfg, model, tc, batch, seq = build(args)
     print(f"arch={cfg.name} params~{cfg.param_count()/1e6:.1f}M "
           f"policy={model.policy.name} container={args.container}")

@@ -123,8 +123,7 @@ def sharded_embed(table: jax.Array, tokens: jax.Array, mesh) -> jax.Array:
         out = jnp.where(ok[..., None], out, jnp.zeros((), out.dtype))
         return jax.lax.psum(out, "model")
 
-    from repro.distributed import sharding as _shd
-    return _shd.shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P("model", None), P(batch_axes, None)),
         out_specs=P(batch_axes, None, None))(table, tokens)

@@ -136,10 +136,12 @@ def ssd_forward(params, h: jax.Array, cfg: ArchConfig,
     total = cum[:, :, -1, :]                       # (B, nc, H)
 
     # --- intra-chunk (quadratic within the chunk) ---
-    # L[i, j] = exp(cum_i - cum_j) for i >= j  (per B, chunk, H)
+    # L[i, j] = exp(cum_i - cum_j) for i >= j  (per B, chunk, H). Masked
+    # before the exp: above the diagonal cum_i - cum_j > 0 can overflow to
+    # inf over a long chunk, and where(mask, inf, 0) has a NaN gradient.
     diff = cum[:, :, :, None, :] - cum[:, :, None, :, :]     # (B,nc,i,j,H)
     mask = jnp.tril(jnp.ones((cs, cs), bool))
-    L = jnp.where(mask[None, None, :, :, None], jnp.exp(diff), 0.0)
+    L = jnp.exp(jnp.where(mask[None, None, :, :, None], diff, -jnp.inf))
     G_ = jnp.einsum("bcihn,bcjhn->bcijh", Cc, Bc)            # C_i . B_j
     M = G_ * L
     xdt = xc * dtc[..., None]

@@ -13,6 +13,8 @@ WORKER = Path(__file__).parent / "worker.py"
 def _run(name, timeout=420):
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    # The child must never reach for an accelerator its parent may hold.
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, str(WORKER), name],
                        capture_output=True, text=True, timeout=timeout,
                        env=env)

@@ -125,8 +125,7 @@ def test_grad_compress():
             out, new_r = grad_compress.psum_compressed(
                 {"w": gl}, {"w": rl}, bits=3, axis_name="pods")
             return out["w"], new_r["w"]
-        from repro.distributed import sharding as shd
-        return shd.shard_map(local, mesh=mesh,
+        return jax.shard_map(local, mesh=mesh,
                              in_specs=(P("pods", None), P("pods", None)),
                              out_specs=(P(None, None), P("pods", None)),
                              check_vma=False)(g, r)

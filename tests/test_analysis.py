@@ -206,3 +206,43 @@ def test_train_parser_rejects_bad_names(capsys):
     args = ap.parse_args(["--arch", "gemma2-2b", "--policy", "qm+qe",
                           "--container", "sfp-m2e4"])
     assert args.policy == "qm+qe" and args.container == "sfp-m2e4"
+
+
+def test_launchers_layers_cut_depth_only():
+    """--layers keeps every published width and cuts whole periods."""
+    import dataclasses
+
+    from repro import configs
+    from repro.configs.base import depth_cut
+    from repro.launch import train
+    full = configs.get("mistral-large-123b")
+    args = train.build_parser().parse_args(
+        ["--arch", full.name, "--preset", "full", "--layers", "2",
+         "--policy", "none"])
+    cfg = train.build(args)[0]
+    assert cfg.n_layers == 2
+    assert dataclasses.replace(cfg, n_layers=full.n_layers) == full
+    gemma3 = configs.get("gemma3-12b")  # period of 6 layer kinds
+    assert depth_cut(gemma3, 6).period == gemma3.period
+    for bad in (0, 5, gemma3.n_layers + 6):
+        with pytest.raises(ValueError):
+            depth_cut(gemma3, bad)
+
+
+def test_compile_cache_dir(monkeypatch):
+    """JAX's own variable wins; otherwise a fixed repo-root directory."""
+    import jax
+
+    from repro.launch import cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+    assert cache.enable_compile_cache() == "/elsewhere"
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        assert cache.enable_compile_cache() == str(cache.REPO_CACHE_DIR)
+        assert cache.REPO_CACHE_DIR.parent == pathlib.Path(__file__).parents[1]
+        assert (jax.config.jax_compilation_cache_dir
+                == str(cache.REPO_CACHE_DIR))
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

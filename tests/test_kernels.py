@@ -179,6 +179,30 @@ def test_flash_attention_gqa_folded_matches_oracle(H, KH, window, softcap):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
 
+def test_flash_attention_grad_matches_oracle():
+    """The kernel path of ops.attention is differentiable (train
+    attention at short S uses it): its gradient is the reference's."""
+    B, S, H, KH, D = 1, 64, 4, 2, 64
+    ks = jax.random.split(jax.random.PRNGKey(9), 4)
+    q = jax.random.normal(ks[0], (B, S, H, D), jnp.float32)
+    k = jax.random.normal(ks[1], (B, S, KH, D), jnp.float32)
+    v = jax.random.normal(ks[2], (B, S, KH, D), jnp.float32)
+    w = jax.random.normal(ks[3], (B, S, H, D), jnp.float32)
+
+    def loss(q, k, v):
+        return jnp.sum(ops.attention(q, k, v, causal=True, window=32) * w)
+
+    grads = {}
+    for backend in ("interpret", "ref"):
+        ops.force_backend(backend)
+        try:
+            grads[backend] = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+        finally:
+            ops.force_backend(None)
+    for g, r in zip(grads["interpret"], grads["ref"]):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=1e-5)
+
+
 def test_flash_attention_bf16():
     B, S, H, D = 1, 128, 2, 128
     ks = jax.random.split(jax.random.PRNGKey(6), 3)

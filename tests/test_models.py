@@ -85,6 +85,20 @@ def test_ssd_chunked_matches_naive_recurrence():
                                atol=1e-3, rtol=1e-3)
 
 
+def test_ssd_gradient_finite_over_long_chunk():
+    """Large dt over a 128-step chunk drives exp(cum_i - cum_j) above the
+    diagonal past f32 range; the masked-out entries must not make the
+    gradient NaN (full-width mamba2 training hit this on its first step)."""
+    cfg = _cfg("mamba2-370m", ssm_chunk=128)
+    p = common.ParamFactory("params", jax.random.PRNGKey(0), jnp.float32)
+    params = mamba2.ssd_init(p, cfg)
+    params["dt_bias"] = jnp.full_like(params["dt_bias"], 2.0)  # dt ~ 2
+    h = jax.random.normal(jax.random.PRNGKey(1), (1, 128, cfg.d_model))
+    g = jax.grad(lambda prm: jnp.sum(mamba2.ssd_forward(prm, h, cfg)))(params)
+    for leaf in jax.tree.leaves(g):
+        assert np.isfinite(np.asarray(leaf)).all()
+
+
 @pytest.mark.slow
 def test_ssd_prefill_state_matches_decode_continuation():
     cfg = _cfg("mamba2-370m")

@@ -121,7 +121,12 @@ def test_ops_paged_dispatch_ref_vs_interpret():
                 np.float32)
         finally:
             ops.force_backend(None)
-    np.testing.assert_array_equal(outs["ref"], outs["interpret"])
+    # The ref dispatch runs the oracle op by op, the kernel as one jitted
+    # program: XLA fuses and orders the f32 softmax/accumulation math
+    # differently, so results agree to f32 rounding, not bit for bit
+    # (the jitted oracle above is still bit-exact).
+    np.testing.assert_allclose(outs["ref"], outs["interpret"],
+                               rtol=1e-5, atol=1e-6)
 
 
 def test_trailing_trash_blocks_are_exact_noops():

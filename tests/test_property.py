@@ -166,10 +166,10 @@ from test_dense_codecs import py_plane_unpack as _py_plane_unpack  # noqa: E402
        st.integers(0, 2 ** 31 - 1), st.integers(0, 127))
 def test_plane_expansion_all_widths_vs_python_oracle(payload, rows, seed,
                                                      tail):
-    """The SWAR plane transpose (pack and the byte-granular expansion)
-    is bit-exact against the loop oracle for every payload width 3..16,
-    including a tail-padded final row (only ``128 - tail`` live lanes —
-    the ragged end of a cache whose length is not a lane multiple)."""
+    """The plane transpose (pack and expansion) is bit-exact against the
+    loop oracle for every payload width 3..16, including a tail-padded
+    final row (only ``128 - tail`` live lanes — the ragged end of a cache
+    whose length is not a lane multiple)."""
     rng = np.random.RandomState(seed)
     words = rng.randint(0, 1 << payload, size=(rows, 128)).astype(np.int32)
     if tail:
@@ -220,7 +220,7 @@ def test_prefix_plane_expansion_equals_truncated_pack(man, dexp, cut, vals):
     np.testing.assert_array_equal(base_wide, base_narrow)
     # The leading planes are byte-for-byte the narrow container's pack...
     np.testing.assert_array_equal(sliced, _py_planes(narrow_words, pp))
-    # ...and the SWAR expansion of the slice yields the truncated words.
+    # ...and the expansion of the slice yields the truncated words.
     np.testing.assert_array_equal(
         np.asarray(ref.plane_unpack_words(jnp.asarray(sliced), pp)),
         narrow_words)
