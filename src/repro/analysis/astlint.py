@@ -70,7 +70,7 @@ _CONTAINER_RE = r"(sfp|gecko|bit_?exact)[\w+-]*"
 # host-side mutation — illegal inside a traced scope.
 _OBS_MUTATORS = {"inc", "dec", "set", "observe", "emit", "event",
                  "instant", "begin", "end", "complete", "record_train",
-                 "record_serve", "write"}
+                 "record_serve", "write", "span"}
 _OBS_RECEIVERS = {"obs", "tracer", "timeline", "registry", "events"}
 
 

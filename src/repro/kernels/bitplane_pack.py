@@ -100,13 +100,14 @@ def _plane_pack_call(x, n, *, fields: kref.PackFields, block_rows: int,
         planes, bases = pl.pallas_call(
             functools.partial(_bitplane_pack_kernel, spec=spec,
                               fields=fields),
-            grid=grid, in_specs=[row_spec], out_specs=out_specs,
-            out_shape=out_shape, interpret=interpret)(rows2d)
+            name="bitplane_pack", grid=grid, in_specs=[row_spec],
+            out_specs=out_specs, out_shape=out_shape,
+            interpret=interpret)(rows2d)
     else:
         planes, bases = pl.pallas_call(
             functools.partial(_bitplane_quantize_pack_kernel, spec=spec,
                               fields=fields),
-            grid=grid,
+            name="bitplane_quantize_pack", grid=grid,
             in_specs=[pl.BlockSpec((1, 1), lambda i: (0, 0)), row_spec],
             out_specs=out_specs, out_shape=out_shape,
             interpret=interpret)(jnp.asarray(n, jnp.int32).reshape(1, 1),
@@ -162,6 +163,7 @@ def bitplane_unpack(planes: jax.Array, bases: jax.Array, *, shape: tuple,
 
     out = pl.pallas_call(
         functools.partial(_bitplane_unpack_kernel, spec=spec, fields=fields),
+        name="bitplane_unpack",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_rows, pb), lambda i: (i, 0)),

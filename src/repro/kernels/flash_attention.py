@@ -121,6 +121,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         functools.partial(_flash_kernel, block_q=block_q, block_k=block_k,
                           seq_k=Sk, causal=causal, window=window,
                           softcap=softcap, scale=scale, q_rep=q_rep),
+        name="flash_attention",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),

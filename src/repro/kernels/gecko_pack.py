@@ -70,6 +70,7 @@ def gecko_pack(groups: jax.Array, *,
 
     bases, widths, planes = pl.pallas_call(
         _gecko_pack_kernel,
+        name="gecko_pack",
         grid=grid,
         in_specs=[pl.BlockSpec((block_groups, kref.GECKO_GROUP),
                                lambda i: (i, 0))],
@@ -108,6 +109,7 @@ def gecko_unpack(bases: jax.Array, planes: jax.Array, *,
 
     out = pl.pallas_call(
         _gecko_unpack_kernel,
+        name="gecko_unpack",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_groups, 8), lambda i: (i, 0)),

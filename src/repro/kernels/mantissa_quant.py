@@ -55,6 +55,7 @@ def mantissa_quantize(x: jax.Array, n: jax.Array, *,
 
     out = pl.pallas_call(
         functools.partial(_quant_kernel, spec=spec),
+        name="mantissa_quantize",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1), lambda i: (0, 0)),  # scalar n

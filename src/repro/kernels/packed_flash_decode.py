@@ -53,6 +53,11 @@ from repro.kernels.flash_attention import NEG_INF, _vmem_scratch
 DEFAULT_BLOCK_L = 128
 
 
+def _geometry(fields: kref.PackFields) -> str:
+    """Kernel-name suffix: dense bit planes, or fixed lanes."""
+    return "planes" if fields.dense else "lanes"
+
+
 def vmem_estimate(*, fields: kref.PackFields, H: int, KH: int, hd: int,
                   block_l: int = DEFAULT_BLOCK_L, dtype=jnp.bfloat16) -> int:
     """Static per-grid-step VMEM footprint model, in bytes.
@@ -216,6 +221,7 @@ def packed_flash_decode(q: jax.Array, k_payload: jax.Array,
                           hd=hd, window=window, softcap=softcap, scale=scale,
                           fields=fields, spec=spec,
                           prefix_planes=prefix_planes),
+        name="packed_flash_decode_" + _geometry(fields),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KH, rep, hd), q.dtype),
         interpret=interpret,
@@ -357,6 +363,7 @@ def paged_flash_decode(q: jax.Array, k_payload: jax.Array,
         functools.partial(_paged_kernel, block_l=block_l, nb=nb, KH=KH,
                           hd=hd, softcap=softcap, scale=scale, fields=fields,
                           spec=spec, prefix_planes=prefix_planes),
+        name="paged_flash_decode_" + _geometry(fields),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KH, rep, hd), q.dtype),
         interpret=interpret,

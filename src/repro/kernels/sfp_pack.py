@@ -116,6 +116,7 @@ def sfp_pack(x: jax.Array, *, fields: kref.PackFields,
 
     payload, bases = pl.pallas_call(
         functools.partial(_pack_kernel, spec=spec, fields=fields),
+        name="sfp_pack",
         grid=grid,
         in_specs=[pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))],
         out_specs=[
@@ -152,6 +153,7 @@ def sfp_quantize_pack(x: jax.Array, n: jax.Array, *, fields: kref.PackFields,
 
     payload, bases = pl.pallas_call(
         functools.partial(_quantize_pack_kernel, spec=spec, fields=fields),
+        name="sfp_quantize_pack",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1), lambda i: (0, 0)),  # scalar n
@@ -191,6 +193,7 @@ def sfp_unpack(payload: jax.Array, bases: jax.Array, *, shape: tuple,
 
     out = pl.pallas_call(
         functools.partial(_unpack_kernel, spec=spec, fields=fields),
+        name="sfp_unpack",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),

@@ -208,9 +208,9 @@ def run_trace(args, built=None, on_step=None):
         "ttft_s_p50": round(sched._h_ttft.percentile(0.50), 6),
         "ttft_s_p95": round(sched._h_ttft.percentile(0.95), 6),
         "ttft_s_p99": round(sched._h_ttft.percentile(0.99), 6),
-        "token_latency_s_p50": round(sched._h_tok.percentile(0.50), 6),
-        "token_latency_s_p95": round(sched._h_tok.percentile(0.95), 6),
-        "token_latency_s_p99": round(sched._h_tok.percentile(0.99), 6),
+        "itl_s_p50": round(sched._h_itl.percentile(0.50), 6),
+        "itl_s_p95": round(sched._h_itl.percentile(0.95), 6),
+        "itl_s_p99": round(sched._h_itl.percentile(0.99), 6),
         "pool_blocks": pool.num_blocks, "pool_peak_used": pool.peak_used,
         "block_l": eng.block_l, "max_slots": eng.max_slots,
         "max_len": eng.max_len,

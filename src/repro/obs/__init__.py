@@ -25,7 +25,8 @@ from typing import Any
 from repro.obs.registry import (EventLog, MetricsRegistry,  # noqa: F401
                                 log_buckets)
 from repro.obs.timeline import PrecisionTimeline  # noqa: F401
-from repro.obs.trace import SpanTracer  # noqa: F401
+from repro.obs.trace import (SpanTracer, profiled_spans,  # noqa: F401
+                             span)
 
 
 class Obs:
@@ -53,6 +54,11 @@ class Obs:
 
     def event(self, name: str, **fields: Any) -> None:
         self.events.emit(name, **fields)
+
+    def span(self, name: str):
+        """Context manager: a phase of the host loop (``trace.span``), on
+        the tracer's ``scheduler`` lane when the tracer is on."""
+        return span(name, self.tracer)
 
     def flush(self) -> None:
         """Write every file-backed exporter; safe to call repeatedly."""

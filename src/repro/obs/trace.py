@@ -14,12 +14,24 @@ Timestamps are microseconds from a monotonic clock; the tracer never
 touches device values, so it adds no host sync — callers hand it host
 scalars only, after any jitted step has already been consumed at the
 host boundary.
+
+``span`` marks a phase of the host loop (``serve.step``, ``serve.decode``,
+...) on the profiler's own clock: a ``jax.profiler.TraceAnnotation``, so a
+``jax.profiler`` capture shows the phase on its host thread above the
+device ops it waited for. With a tracer it also lands on the tracer's
+``scheduler`` lane. While a profiler records, each closed span is kept in
+``profiled_spans()`` too, so the process can read back the phases it put
+in the capture without parsing the capture's file.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import time
-from typing import Any, NamedTuple
+from collections import deque
+from typing import Any, Iterator, NamedTuple
+
+from jax.profiler import TraceAnnotation
 
 _PID = 1  # single-process: one row in the viewer
 
@@ -97,3 +109,36 @@ class SpanTracer:
 
     def lanes(self) -> list[str]:
         return list(self._tids)
+
+
+# (name, start_s, end_s) on time.perf_counter, one per span closed while a
+# profiler recorded; bounded, so the oldest go first.
+_PROFILED: deque[tuple[str, float, float]] = deque(maxlen=1 << 16)
+
+
+@contextlib.contextmanager
+def span(name: str, tracer: SpanTracer | None = None) -> Iterator[None]:
+    """A phase of the host loop, named ``name``. Inert (no annotation, no
+    record) when no profiler runs and ``tracer`` is None. Host code only:
+    inside jitted code it would mark tracing, not the step."""
+    profiling = TraceAnnotation.is_enabled()
+    if not profiling and tracer is None:
+        yield
+        return
+    opened = tracer.begin(name, "scheduler") if tracer is not None else None
+    t0 = time.perf_counter()
+    try:
+        with TraceAnnotation(name):
+            yield
+    finally:
+        if profiling:
+            _PROFILED.append((name, t0, time.perf_counter()))
+        if opened is not None:
+            tracer.end(opened)
+
+
+def profiled_spans(lo: float = float("-inf"),
+                   hi: float = float("inf")) -> list[tuple[str, float, float]]:
+    """Spans closed while a profiler recorded that lie wholly inside
+    [``lo``, ``hi``] (``time.perf_counter`` seconds), oldest first."""
+    return [s for s in _PROFILED if s[1] >= lo and s[2] <= hi]

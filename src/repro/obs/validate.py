@@ -15,7 +15,7 @@ CLI (what the CI observability smoke runs)::
         --require-chain --require-downshift
 
 Beyond schema-shape it checks the semantic acceptance criteria: the
-Prometheus text parses and carries the TTFT/latency histograms, the
+Prometheus text parses and carries the TTFT/ITL histograms, the
 trace holds >=1 complete request span chain (submit -> queued ->
 prefill -> decode -> retire), ``--require-downshift`` demands a
 downshift-annotated prefill span, and every serve timeline entry's
@@ -190,14 +190,14 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--schemas-dir", default="tests/fixtures/obs")
     ap.add_argument("--require-chain", action="store_true",
                     help="demand >=1 complete request span chain and the "
-                         "TTFT/latency histograms")
+                         "TTFT/ITL histograms")
     ap.add_argument("--require-downshift", action="store_true",
                     help="demand a downshift-annotated prefill span")
     args = ap.parse_args(argv)
 
     errs: list[str] = []
     if args.metrics:
-        req = (("serve_ttft_seconds", "serve_token_latency_seconds")
+        req = (("serve_ttft_seconds", "serve_itl_seconds")
                if args.require_chain else ())
         errs += validate_prometheus(args.metrics, req)
     if args.trace:

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import codecs
+from repro import obs as obs_mod
 from repro.configs.base import ArchConfig, GLOBAL, LOCAL, SSD
 from repro.kernels import ops
 from repro.models import attention, mamba2, rglru
@@ -277,16 +278,14 @@ class PagedEngine:
         self.integrity = bool(integrity)
         self._sums_fn = jax.jit(self._block_sums_fn)
         self.expected_sums = np.zeros(self.pool.num_blocks + 1, np.uint32)
-        # Telemetry sink (repro.obs.Obs); the driving Scheduler installs
-        # its own. All recording happens at host boundaries — after the
-        # jitted call's outputs were pulled to numpy — never inside
-        # traced code (enforced by the obs-no-hot-path-sync lint).
-        self.obs: Optional[Any] = None
+        # Telemetry sink; the driving Scheduler installs its own. All
+        # recording happens at host boundaries — after the jitted call's
+        # outputs were pulled to numpy — never inside traced code
+        # (enforced by the obs-no-hot-path-sync lint).
+        self.obs = obs_mod.Obs()
 
     def _observe(self, name: str, help: str, seconds: float) -> None:
-        if self.obs is not None:
-            self.obs.registry.histogram(name, help,
-                                        unit="s").observe(seconds)
+        self.obs.registry.histogram(name, help, unit="s").observe(seconds)
 
     # -- device memory ---------------------------------------------------
 
@@ -351,7 +350,8 @@ class PagedEngine:
 
     def block_checksums(self) -> np.ndarray:
         """Current checksums of every physical block (trash block = id 0)."""
-        return np.asarray(self._sums_fn(self.mem))
+        with self.obs.span("serve.checksums"):
+            return np.asarray(self._sums_fn(self.mem))
 
     def verify_blocks(self, ids) -> list:
         """Return the subset of physical block ids whose packed planes no
@@ -408,8 +408,7 @@ class PagedEngine:
                 a.at[(slice(None), int(phys)) if a.ndim == 4
                      else int(phys)].set(0) for a in kv))
         self.refresh_checksums([phys])
-        if self.obs is not None:
-            self.obs.event("scrub_block", block=int(phys))
+        self.obs.event("scrub_block", block=int(phys))
 
     # -- prefill ---------------------------------------------------------
 

@@ -66,6 +66,8 @@ from repro.serve.engine import PagedEngine
 from repro.serve.pool import TRASH_BLOCK, blocks_for
 
 OnToken = Callable[[Any, int, bool], None]
+# Latency histogram bounds: 20 a decade, steps of about 12%.
+LATENCY_BUCKETS = obs_mod.log_buckets(per_decade=20)
 
 
 @dataclasses.dataclass
@@ -248,17 +250,28 @@ class Scheduler:
             "serve_spec_rounds_total",
             "speculative draft+verify rounds dispatched")
         self._h_ttft = reg.histogram(
-            "serve_ttft_seconds", "submit-to-first-token wall time",
-            unit="s")
-        self._h_tok = reg.histogram(
-            "serve_token_latency_seconds",
-            "per-token wall time within a scheduler step", unit="s")
+            "serve_ttft_seconds", "arrival-to-first-token time (from "
+            "submit when step() is given no clock)", unit="s",
+            bounds=LATENCY_BUCKETS)
+        self._h_queue = reg.histogram(
+            "serve_queue_wait_seconds", "arrival-to-admission time (from "
+            "submit when step() is given no clock)", unit="s",
+            bounds=LATENCY_BUCKETS)
+        self._h_itl = reg.histogram(
+            "serve_itl_seconds", "gap between a request's consecutive "
+            "tokens", unit="s", bounds=LATENCY_BUCKETS)
         self._h_step = reg.histogram(
-            "serve_step_seconds", "scheduler step wall time", unit="s")
+            "serve_step_seconds", "scheduler step wall time", unit="s",
+            bounds=LATENCY_BUCKETS)
         self.stats = SchedulerStats(reg)
         self._submit_ts: Dict[Any, float] = {}   # uid -> perf_counter at
-        #                                          submit (TTFT, first
-        #                                          residency only)
+        #                                          submit (until its first
+        #                                          token)
+        self._last_tok_ts: Dict[Any, float] = {}  # uid -> perf_counter at
+        #                                           its latest token (ITL)
+        # (now, perf_counter) at the start of a step given the caller's
+        # clock, else None: places a moment of the step on that clock.
+        self._clock: Optional[Tuple[float, float]] = None
         self._queued_spans: Dict[Any, Any] = {}  # uid -> open queued span
         self._step_i = 0
         self._admit_seq = 0
@@ -349,6 +362,7 @@ class Scheduler:
         # always equals serve_submitted_total once the queue drains.
         self._c_requests.labels(outcome=status).inc()
         self._submit_ts.pop(uid, None)
+        self._last_tok_ts.pop(uid, None)
         tracer = self.obs.tracer
         if tracer is not None:
             q = self._queued_spans.pop(uid, None)
@@ -375,20 +389,34 @@ class Scheduler:
 
     # -- internals -------------------------------------------------------
 
+    def _waited(self, req: Request, t_submit: float, t: float) -> float:
+        """Time from ``req``'s arrival to perf_counter ``t``, on the
+        caller's clock when this step was given one, else from submit."""
+        if self._clock is None:
+            return t - t_submit
+        now, t_step = self._clock
+        return now + (t - t_step) - req.arrival
+
     def _emit(self, st: _Running, tok: int) -> Tuple[Any, int, bool]:
+        uid = st.req.uid
         st.emitted.append(int(tok))
         st.last_tok = int(tok)
-        self._history.setdefault(st.req.uid, []).append(int(tok))
+        self._history.setdefault(uid, []).append(int(tok))
         self._c_tokens.inc()
-        t0 = self._submit_ts.pop(st.req.uid, None)
-        if t0 is not None:  # first token this request ever emitted
-            self._h_ttft.observe(time.perf_counter() - t0)
+        t = time.perf_counter()
+        t_sub = self._submit_ts.pop(uid, None)
+        if t_sub is not None:  # first token this request ever emitted
+            self._h_ttft.observe(self._waited(st.req, t_sub, t))
+        t_prev = self._last_tok_ts.get(uid)
+        if t_prev is not None:
+            self._h_itl.observe(t - t_prev)
+        self._last_tok_ts[uid] = t
         done = (len(st.emitted) >= st.req.max_new
                 or st.n_ctx + 1 >= self.engine.max_len)
         for cb in (st.req.on_token, self.on_token):
             if cb is not None:
-                cb(st.req.uid, int(tok), done)
-        return (st.req.uid, int(tok), done)
+                cb(uid, int(tok), done)
+        return (uid, int(tok), done)
 
     def _finish(self, st: _Running) -> None:
         self._retire(st, "ok")
@@ -590,10 +618,15 @@ class Scheduler:
             if req.requeued:
                 recompute += n0
                 self._c_recomp.inc(n0)
+            t_sub = self._submit_ts.get(req.uid)
+            if t_sub is not None:  # first admission
+                self._h_queue.observe(
+                    self._waited(req, t_sub, time.perf_counter()))
             tracer = self.obs.tracer
             t_pf = time.perf_counter()
-            tok0 = self.engine.prefill_into_slot(slot, req.prompt,
-                                                 narrow=degraded)
+            with self.obs.span("serve.prefill"):
+                tok0 = self.engine.prefill_into_slot(slot, req.prompt,
+                                                     narrow=degraded)
             self._admit_seq += 1
             st = _Running(req=req, slot=slot, admit_seq=self._admit_seq,
                           n_ctx=n0, last_tok=tok0, narrow=degraded)
@@ -688,16 +721,13 @@ class Scheduler:
         reads their KV exactly — they effectively draft at their own
         narrower prefix, and verification covers the rest.
         """
-        t0 = time.perf_counter()
-        emitted = self._step_inner(now, burst, speculate, draft_planes)
-        wall = time.perf_counter() - t0
-        self._h_step.observe(wall)
-        if emitted:
-            per = wall / len(emitted)
-            for _ in emitted:
-                self._h_tok.observe(per)
-        if self.obs.timeline is not None:
-            self._record_timeline()
+        with self.obs.span("serve.step"):
+            t0 = time.perf_counter()
+            self._clock = None if now is None else (float(now), t0)
+            emitted = self._step_inner(now, burst, speculate, draft_planes)
+            self._h_step.observe(time.perf_counter() - t0)
+            if self.obs.timeline is not None:
+                self._record_timeline()
         self._step_i += 1
         return emitted
 
@@ -734,57 +764,75 @@ class Scheduler:
         emitted: List[Tuple[Any, int, bool]] = []
         self._expire(now)
         self._shed(now)
-        self._verify_integrity()
-        self._admit(now, emitted)
+        with self.obs.span("serve.verify"):
+            self._verify_integrity()
+        with self.obs.span("serve.admit"):
+            self._admit(now, emitted)
         if not self.running:
             return emitted
         if speculate is not None and int(speculate) < 1:
             raise ValueError(f"speculate must be >= 1, got {speculate}")
         K = self._burst_len(burst if speculate is None else speculate)
-        try:
-            self._ensure_blocks(K)
-        except RuntimeError:
-            if K == 1:
-                raise
-            # Pool too tight for the whole burst horizon even after
-            # evicting everyone else: degrade to single-step pacing
-            # rather than refusing a request burst=1 could serve.
-            K = 1
-            self._ensure_blocks(K)
+        with self.obs.span("serve.ensure_blocks"):
+            try:
+                self._ensure_blocks(K)
+            except RuntimeError:
+                if K == 1:
+                    raise
+                # Pool too tight for the whole burst horizon even after
+                # evicting everyone else: degrade to single-step pacing
+                # rather than refusing a request burst=1 could serve.
+                K = 1
+                self._ensure_blocks(K)
         if not self.running:
             return emitted  # everyone preempted back to the queue
 
-        pool = self.engine.pool
-        toks = np.zeros(self.engine.max_slots, np.int32)
-        pos = np.zeros(self.engine.max_slots, np.int32)
-        for st in self.running.values():
-            toks[st.slot] = st.last_tok
-            pos[st.slot] = st.n_ctx  # the input token's absolute position
-        # Snapshot the participating blocks now: _finish/_recover clear
-        # table rows during replay, and these blocks' checksums must be
-        # re-recorded after the decode wrote fresh KV into them.
-        written = [int(p) for st in self.running.values()
-                   for p in pool.tables[st.slot] if p != TRASH_BLOCK]
-        slot_blocks = {st.slot: tuple(int(p) for p in pool.tables[st.slot]
-                                      if p != TRASH_BLOCK)
-                       for st in self.running.values()}
-        t_dec = time.perf_counter()
-        if speculate is None:
-            nxt, bad = self.engine.decode_burst(toks, pos, K)
-            # Uniform replay: every slot streams all K burst tokens.
-            n_emit = np.full(self.engine.max_slots, K, np.int64)
-            accepted = None
-            self._c_decode.inc(K)
-        else:
-            nxt, bad, accepted, n_emit = self.engine.speculate(
-                toks, pos, K, draft_planes)  # nxt/bad: (K, max_slots)
-            self._c_decode.inc(2 * K)  # K draft + K verify model steps
-            self._c_spec_rounds.inc()
-        dec_wall = time.perf_counter() - t_dec
+        with self.obs.span("serve.inputs"):
+            pool = self.engine.pool
+            toks = np.zeros(self.engine.max_slots, np.int32)
+            pos = np.zeros(self.engine.max_slots, np.int32)
+            for st in self.running.values():
+                toks[st.slot] = st.last_tok
+                pos[st.slot] = st.n_ctx  # the input token's position
+            # Snapshot the participating blocks now: _finish/_recover
+            # clear table rows during replay, and these blocks' checksums
+            # must be re-recorded after the decode wrote fresh KV.
+            written = [int(p) for st in self.running.values()
+                       for p in pool.tables[st.slot] if p != TRASH_BLOCK]
+            slot_blocks = {st.slot: tuple(int(p)
+                                          for p in pool.tables[st.slot]
+                                          if p != TRASH_BLOCK)
+                           for st in self.running.values()}
+        with self.obs.span("serve.decode"):
+            t_dec = time.perf_counter()
+            if speculate is None:
+                nxt, bad = self.engine.decode_burst(toks, pos, K)
+                # Uniform replay: every slot streams all K burst tokens.
+                n_emit = np.full(self.engine.max_slots, K, np.int64)
+                accepted = None
+                self._c_decode.inc(K)
+            else:
+                nxt, bad, accepted, n_emit = self.engine.speculate(
+                    toks, pos, K, draft_planes)  # nxt/bad: (K, max_slots)
+                self._c_decode.inc(2 * K)  # K draft + K verify steps
+                self._c_spec_rounds.inc()
+            dec_wall = time.perf_counter() - t_dec
+        with self.obs.span("serve.replay"):
+            self._replay(K, nxt, bad, accepted, n_emit, dec_wall,
+                         slot_blocks, emitted)
+        with self.obs.span("serve.refresh"):
+            self.engine.refresh_checksums(written)
+        return emitted
 
+    def _replay(self, K: int, nxt, bad, accepted, n_emit, dec_wall: float,
+                slot_blocks: Dict[int, Tuple[int, ...]],
+                emitted: List[Tuple[Any, int, bool]]) -> None:
+        """Stream a round's (K, max_slots) tokens in step order, finish
+        requests at their budget, and recover slots whose logits went
+        non-finite. ``accepted`` is None unless the round speculated."""
         live = list(self.running.values())
         tracer = self.obs.tracer
-        if speculate is not None:
+        if accepted is not None:
             # Acceptance bookkeeping happens before replay (terminal
             # replay paths pop _spec_acc into the request's result).
             for st in live:
@@ -801,7 +849,7 @@ class Scheduler:
             for st in live:
                 geom = (self.engine.degraded_container if st.narrow
                         else self.engine.container)
-                if speculate is None:
+                if accepted is None:
                     tracer.complete(
                         "decode", str(st.req.uid), dec_wall, burst=K,
                         slot=st.slot, n_ctx=st.n_ctx,
@@ -836,8 +884,6 @@ class Scheduler:
             if self.running.get(st.slot) is st:
                 self._c_nan.inc()
                 self._recover(st, slot_blocks[st.slot])
-        self.engine.refresh_checksums(written)
-        return emitted
 
     def run(self, requests=None, now_fn=None, max_steps: int = 100_000,
             burst: int = 1, fault_hook=None,

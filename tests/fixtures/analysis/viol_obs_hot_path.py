@@ -19,6 +19,8 @@ def step(x):
     obs.tracer.instant("mid_step", "train")  # LINT: obs-no-hot-path-sync
     obs.event("fixture_event", val=1.0)  # LINT: obs-no-hot-path-sync
     obs.timeline.record_serve(0, occupancy=0.5)  # LINT: obs-no-hot-path-sync
+    with obs.span("serve.step"):  # LINT: obs-no-hot-path-sync
+        y = y + 1
     return y
 
 

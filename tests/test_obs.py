@@ -10,6 +10,7 @@ import dataclasses
 import json
 import math
 import pathlib
+import time
 
 import jax
 import jax.numpy as jnp
@@ -98,8 +99,7 @@ def test_prometheus_export_round_trips_the_validator(tmp_path):
                 labels=("outcome",)).labels(outcome="ok").inc(3)
     reg.gauge("pool_used_blocks", "used").set(2)
     reg.histogram("serve_ttft_seconds", "ttft", unit="s").observe(0.05)
-    reg.histogram("serve_token_latency_seconds", "tok",
-                  unit="s").observe(0.002)
+    reg.histogram("serve_itl_seconds", "itl", unit="s").observe(0.002)
     text = reg.to_prometheus()
     assert "# TYPE serve_requests_total counter" in text
     assert 'serve_requests_total{outcome="ok"} 3' in text
@@ -107,8 +107,7 @@ def test_prometheus_export_round_trips_the_validator(tmp_path):
     p = tmp_path / "metrics.prom"
     p.write_text(text)
     assert validate_mod.validate_prometheus(
-        str(p), require=("serve_ttft_seconds",
-                         "serve_token_latency_seconds")) == []
+        str(p), require=("serve_ttft_seconds", "serve_itl_seconds")) == []
     # a histogram that was never registered is a hard failure
     errs = validate_mod.validate_prometheus(str(p),
                                             require=("serve_step_seconds",))
@@ -337,6 +336,118 @@ def test_fresh_scheduler_gets_fresh_counters(serving):
     b = Scheduler(eng)
     assert b.stats.submitted == b.stats.finished == 0
     assert eng.obs is b.obs and eng.pool.obs is b.obs
+
+
+# ---------------------------------------------------------------------------
+# serving phases and latency histograms
+# ---------------------------------------------------------------------------
+
+# each phase of Scheduler.step -> the span it nests in
+PHASES = {"serve.verify": "serve.step", "serve.admit": "serve.step",
+          "serve.prefill": "serve.admit", "serve.ensure_blocks": "serve.step",
+          "serve.inputs": "serve.step", "serve.decode": "serve.step",
+          "serve.replay": "serve.step", "serve.refresh": "serve.step"}
+CHECKSUM_PARENTS = {"serve.verify", "serve.refresh", "serve.prefill"}
+
+
+def _parent(i, spans):
+    """Name of the innermost other span that contains span ``i``."""
+    _, s, e = spans[i]
+    outer = [(b - a, n) for j, (n, a, b) in enumerate(spans)
+             if j != i and a <= s and e <= b]
+    return min(outer)[1] if outer else None
+
+
+def test_scheduler_phases_in_a_profiler_trace(serving, tmp_path):
+    """A scheduler run under jax.profiler writes every phase span on the
+    profiler's clock, nested as the step runs them, one ``serve.step`` a
+    step; the process keeps the same spans in ``profiled_spans``."""
+    cfg, model, params = serving
+    eng = engine.PagedEngine(model, params, max_slots=2, max_len=128)
+    Scheduler(eng).run(_reqs(cfg, [5, 5], [3, 3]))  # compiles, untraced
+    sched = Scheduler(eng)
+    for r in _reqs(cfg, [5, 5], [3, 3], seed=1):
+        sched.submit(r)
+    steps = 0
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        t0 = time.perf_counter()
+        while not sched.idle:
+            sched.step()
+            steps += 1
+        t1 = time.perf_counter()
+    finally:
+        jax.profiler.stop_trace()
+    data = jax.profiler.ProfileData.from_file(
+        str(next(tmp_path.rglob("*.xplane.pb"))))
+    spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+             for p in data.planes for line in p.lines for e in line.events
+             if e.name.startswith("serve.")]
+    names = [n for n, _, _ in spans]
+    assert set(names) == set(PHASES) | {"serve.step", "serve.checksums"}
+    assert names.count("serve.step") == steps == 2
+    assert names.count("serve.prefill") == 2
+    for i, name in enumerate(names):
+        parent = _parent(i, spans)
+        if name == "serve.step":
+            assert parent is None
+        elif name == "serve.checksums":
+            assert parent in CHECKSUM_PARENTS
+        else:
+            assert parent == PHASES[name], (name, parent)
+    mirror = obs_mod.profiled_spans(t0, t1)
+    assert sorted(n for n, _, _ in mirror) == sorted(names)
+
+
+def test_phase_spans_inert_without_profiler_and_on_the_tracer_lane(serving):
+    cfg, model, params = serving
+    eng = engine.PagedEngine(model, params, max_slots=2, max_len=128)
+    before = len(obs_mod.profiled_spans())
+    Scheduler(eng).run(_reqs(cfg, [5], [2]))
+    assert len(obs_mod.profiled_spans()) == before
+    sched = Scheduler(eng, obs=obs_mod.Obs(trace=True))
+    sched.run(_reqs(cfg, [5], [3]))
+    lane = sched.obs.tracer.spans(lane="scheduler")
+    assert len([e for e in lane if e["name"] == "serve.step"]) == 2
+    assert {e["name"] for e in lane} >= set(PHASES) - {"serve.verify"}
+
+
+def test_ttft_and_queue_wait_from_arrival_and_itl_per_request(serving):
+    """With the caller's clock, TTFT and queue wait count from arrival;
+    without one, from submit. ITL is one sample per token after a
+    request's first."""
+    cfg, model, params = serving
+    eng = engine.PagedEngine(model, params, max_slots=1, max_len=128)
+    clock = {"t": 0.0}
+
+    def now():
+        clock["t"] += 100.0
+        return clock["t"]
+
+    sched = Scheduler(eng)
+    # one slot: r0 admitted at t=100, finishes at t=200; r1 admitted at 300
+    sched.run(_reqs(cfg, [4, 4], [3, 2]), now_fn=now)
+    reg = sched.obs.registry
+    for name in ("serve_ttft_seconds", "serve_queue_wait_seconds"):
+        h = reg.histogram(name)._solo()
+        assert h.count == 2, name
+        assert 100.0 <= h.min < 150.0 and 300.0 <= h.max < 350.0, name
+    itl = reg.histogram("serve_itl_seconds")._solo()
+    assert itl.count == 5 - 2 and 0.0 <= itl.max < 50.0
+    plain = Scheduler(eng)
+    plain.run(_reqs(cfg, [4, 4], [3, 2]))
+    for name in ("serve_ttft_seconds", "serve_queue_wait_seconds"):
+        h = plain.obs.registry.histogram(name)._solo()
+        assert h.count == 2 and 0.0 < h.max < 50.0, name
+    assert plain.obs.registry.histogram("serve_itl_seconds")._solo() \
+        .count == 3
+
+
+def test_latency_histograms_step_about_twelve_percent():
+    b = log_buckets(per_decade=20)
+    ratios = [y / x for x, y in zip(b, b[1:])]
+    assert all(math.isclose(r, 10 ** 0.05) for r in ratios)
+    assert math.isclose(b[0], 1e-5) and math.isclose(b[-1], 100.0)
 
 
 # ---------------------------------------------------------------------------

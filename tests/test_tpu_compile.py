@@ -60,12 +60,16 @@ def test_paged_flash_decode_compiles(one_chip, container):
     part = _spec(one_chip, (n_phys, BLOCK_L, f.nd_payload_cols(D)),
                  f.payload_dtype)
     bases = _spec(one_chip, (n_phys, BLOCK_L, D // 128), jnp.uint8)
-    _assert_kernel(_compile(
+    compiled = _compile(
         lambda q, kp, kb, vp, vb, t, p: pfd.paged_flash_decode(
             q, kp, kb, vp, vb, t, p, fields=f, interpret=False),
         _spec(one_chip, (B, 1, H, HD), jnp.bfloat16), part, bases, part,
         bases, _spec(one_chip, (B, nb), jnp.int32),
-        _spec(one_chip, (B,), jnp.int32)))
+        _spec(one_chip, (B,), jnp.int32))
+    _assert_kernel(compiled)
+    # the op the benchmark's trace reduction matches, named by geometry
+    geometry = {"sfp8": "lanes", "sfp-m2e4": "planes"}[container]
+    assert f"%paged_flash_decode_{geometry}." in compiled.as_text()
 
 
 @pytest.mark.parametrize("container", ["sfp8", "sfp-m2e4"])
