@@ -29,7 +29,9 @@ in a request-agnostic pool and each row's logical blocks are gathered
 through its block table *inside the grid* — the table is a scalar-prefetch
 operand consumed by the BlockSpec index_maps, so each (row, block) step
 DMAs its physical block straight from the HBM pool. Same recurrence, same
-bit machine, same masks.
+bit machine, same masks; the logical blocks past each row's position are
+skipped (neither fetched nor expanded), so a row's cost follows its
+length rather than the table's.
 
 Oracles: ``ref.packed_flash_decode`` / ``ref.paged_flash_decode``
 (unpack-then-attend with the same block recurrence) — bit-exact against
@@ -241,6 +243,14 @@ def _paged_kernel(tab_ref, pos_ref, q_ref, kp_ref, kb_ref, vp_ref, vb_ref,
     is the contiguous decode kernel's on logical slots — the recurrence,
     masking and bit machine are shared, which is what makes paged decode
     bit-exact against the contiguous kernel over the same logical cache.
+
+    Logical blocks past the row's position (``ki * block_l > pos``) are
+    skipped: the index_map re-points them at the row's last live block
+    (no new DMA) and the body below does not run, so they are neither
+    fetched nor expanded. Block 0 always holds slot 0 <= pos, so the
+    running max is finite before any skip, and each skipped block would
+    have contributed exactly p == 0, alpha == 1 — the output is the one
+    the masked recurrence over every block gives.
     """
     b = pl.program_id(0)
     ki = pl.program_id(1)
@@ -254,34 +264,38 @@ def _paged_kernel(tab_ref, pos_ref, q_ref, kp_ref, kb_ref, vp_ref, vb_ref,
     pos = pos_ref[b]
     L = nb * block_l
 
-    # Same softmax-fused per-tile expansion as the contiguous kernel — one
-    # shared decompressor body (ref.unpack_tile) for both grids.
-    k = kref.unpack_tile(kp_ref[0], kb_ref[0], fields, spec, rows=block_l,
-                         KH=KH, hd=hd, prefix_planes=prefix_planes)
-    v = kref.unpack_tile(vp_ref[0], vb_ref[0], fields, spec, rows=block_l,
-                         KH=KH, hd=hd, prefix_planes=prefix_planes)
-    q = q_ref[0].astype(jnp.float32)
+    @pl.when(ki * block_l <= pos)
+    def _live_block():
+        # Same softmax-fused per-tile expansion as the contiguous kernel —
+        # one shared decompressor body (ref.unpack_tile) for both grids.
+        k = kref.unpack_tile(kp_ref[0], kb_ref[0], fields, spec,
+                             rows=block_l, KH=KH, hd=hd,
+                             prefix_planes=prefix_planes)
+        v = kref.unpack_tile(vp_ref[0], vb_ref[0], fields, spec,
+                             rows=block_l, KH=KH, hd=hd,
+                             prefix_planes=prefix_planes)
+        q = q_ref[0].astype(jnp.float32)
 
-    s = jnp.einsum("hgd,lhd->hgl", q, k) * scale
-    if softcap is not None:
-        s = softcap * jnp.tanh(s / softcap)
+        s = jnp.einsum("hgd,lhd->hgl", q, k) * scale
+        if softcap is not None:
+            s = softcap * jnp.tanh(s / softcap)
 
-    # Masking is on *logical* slots: logical blocks past the row's
-    # allocation point at the reserved trash block, and their slots exceed
-    # pos — an exact no-op in the recurrence (p == 0, alpha == 1).
-    slots = ki * block_l + jax.lax.broadcasted_iota(
-        jnp.int32, (1, 1, block_l), 2)
-    valid = kref.decode_kv_mask(pos, L, None, slots=slots)
-    s = jnp.where(valid, s, NEG_INF)
+        # Masking is on *logical* slots: only the row's last live block
+        # holds slots past pos here.
+        slots = ki * block_l + jax.lax.broadcasted_iota(
+            jnp.int32, (1, 1, block_l), 2)
+        valid = kref.decode_kv_mask(pos, L, None, slots=slots)
+        s = jnp.where(valid, s, NEG_INF)
 
-    m_prev = m_scr[...]
-    m_cur = jnp.max(s, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    p = jnp.exp(s - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + jnp.einsum("hgl,lhd->hgd", p, v)
-    m_scr[...] = m_new
+        m_prev = m_scr[...]
+        m_cur = jnp.max(s, axis=-1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[...] = (acc_scr[...] * alpha
+                        + jnp.einsum("hgl,lhd->hgd", p, v))
+        m_scr[...] = m_new
 
     @pl.when(ki == nb - 1)
     def _finalize():
@@ -308,10 +322,13 @@ def paged_flash_decode(q: jax.Array, k_payload: jax.Array,
     prefetch operand, so the *gather happens inside the kernel grid*: each
     (b, j) step's index_map DMAs physical block ``tables[b, j]`` straight
     from the HBM pool into VMEM — no contiguous per-request cache ever
-    materializes. Logical blocks past a row's allocation must point at a
-    valid (trash) physical block; position masking makes them exact
-    no-ops. Global attention only (local ring buffers are window-bounded
-    and stay per-slot contiguous). Returns (B, 1, H, hd) in q's dtype.
+    materializes. Logical blocks past a row's position are skipped:
+    their steps re-point at the row's last live block, so they are
+    neither fetched nor expanded, and the output is bit-identical to
+    attending them masked. Their table entries must still be valid
+    physical indices (the reserved trash block is one). Global attention
+    only (local ring buffers are window-bounded and stay per-slot
+    contiguous). Returns (B, 1, H, hd) in q's dtype.
 
     Oracle: ``ref.paged_flash_decode`` — bit-exact in interpret mode,
     equal to f32 rounding when compiled for a TPU.
@@ -336,20 +353,21 @@ def paged_flash_decode(q: jax.Array, k_payload: jax.Array,
     tables = tables.astype(jnp.int32)
     scale = 1.0 / (hd ** 0.5)
 
+    def kv_block(b, j, tab, pos):
+        # Steps past the row's last live block repeat its index, so the
+        # pipeline issues no copy for them (the body skips them too).
+        return (tab[b, jnp.minimum(j, pos[b] // block_l)], 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # (tables, pos) — available to index_maps
         grid=(B, nb),
         in_specs=[
             pl.BlockSpec((1, KH, rep, hd),
                          lambda b, j, tab, pos: (b, 0, 0, 0)),
-            pl.BlockSpec((1, block_l, Dp),
-                         lambda b, j, tab, pos: (tab[b, j], 0, 0)),
-            pl.BlockSpec((1, block_l, G),
-                         lambda b, j, tab, pos: (tab[b, j], 0, 0)),
-            pl.BlockSpec((1, block_l, Dp),
-                         lambda b, j, tab, pos: (tab[b, j], 0, 0)),
-            pl.BlockSpec((1, block_l, G),
-                         lambda b, j, tab, pos: (tab[b, j], 0, 0)),
+            pl.BlockSpec((1, block_l, Dp), kv_block),
+            pl.BlockSpec((1, block_l, G), kv_block),
+            pl.BlockSpec((1, block_l, Dp), kv_block),
+            pl.BlockSpec((1, block_l, G), kv_block),
         ],
         out_specs=pl.BlockSpec((1, KH, rep, hd),
                                lambda b, j, tab, pos: (b, 0, 0, 0)),

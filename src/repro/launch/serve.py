@@ -194,12 +194,19 @@ def run_trace(args, built=None, on_step=None):
     s = sched.stats
     pool = eng.pool.stats()
     n = max(1, len(reqs))
+    kv_blocks = sched.obs.registry.counter("serve_decode_kv_blocks_total",
+                                           labels=("kind",))
     report = {
         "arch": cfg.name, "container": container,
         "requests": len(reqs), "emitted_tokens": total,
         "wall_s": round(dt, 2), "tok_per_s": round(total / max(dt, 1e-9), 1),
         "decode_steps": s.decode_steps,
         "mean_batch_occupancy": round(total / max(s.decode_steps, 1), 2),
+        # Share of the paged decode grid's KV block steps that hold a
+        # live slot (the rest are skipped by the kernel).
+        "decode_kv_live_share": round(
+            kv_blocks.total(kind="live")
+            / max(1.0, kv_blocks.total(kind="grid")), 4),
         "preemptions": s.preemptions,
         "mean_ttft_steps": round(float(np.mean(list(ttft.values()))), 2)
         if ttft else None,

@@ -287,6 +287,29 @@ class PagedEngine:
     def _observe(self, name: str, help: str, seconds: float) -> None:
         self.obs.registry.histogram(name, help, unit="s").observe(seconds)
 
+    def _count_kv_blocks(self, pos: np.ndarray, steps: int = 1,
+                         passes: int = 1) -> None:
+        """Count the paged decode kernel's KV block steps, on the host.
+
+        ``grid`` is every (slot, logical block) step of a decode step;
+        ``live`` the steps whose block holds a slot <= the row's position
+        — the only ones the kernel fetches and expands. ``steps``
+        consecutive positions from ``pos`` (a burst), each run ``passes``
+        times (a speculative round drafts and verifies them). Computed
+        from the host ``pos`` array the caller already holds, so counting
+        never waits on the device.
+        """
+        at = (np.asarray(pos, np.int64)[None, :]
+              + np.arange(steps, dtype=np.int64)[:, None])
+        live = (at // self.block_l + 1).sum()
+        fam = self.obs.registry.counter(
+            "serve_decode_kv_blocks_total",
+            "paged decode KV block steps per decode step: live (expanded) "
+            "and grid (all)", labels=("kind",))
+        fam.labels(kind="live").inc(passes * int(live))
+        fam.labels(kind="grid").inc(
+            passes * steps * self.max_slots * self.nmax)
+
     # -- device memory ---------------------------------------------------
 
     def _slot_mem(self, kind: str):
@@ -585,6 +608,8 @@ class PagedEngine:
             self.params, self.mem, tables,
             jnp.asarray(toks, jnp.int32)[:, None],
             jnp.asarray(pos, jnp.int32))
+        # Counted while the step runs (dispatch is asynchronous).
+        self._count_kv_blocks(pos)
         self.decode_steps += 1
         out = np.asarray(nxt), np.asarray(bad)
         self._observe("serve_decode_seconds",
@@ -644,6 +669,7 @@ class PagedEngine:
         out, bad, self.mem = fn(self.params, self.mem, tables,
                                 jnp.asarray(toks, jnp.int32)[:, None],
                                 jnp.asarray(pos, jnp.int32))
+        self._count_kv_blocks(pos, K)
         self.decode_steps += K
         res = np.asarray(out), np.asarray(bad)
         self._observe("serve_decode_seconds",
@@ -816,6 +842,7 @@ class PagedEngine:
             self.params, self.mem, tables,
             jnp.asarray(toks, jnp.int32)[:, None],
             jnp.asarray(pos, jnp.int32))
+        self._count_kv_blocks(pos, K, passes=2)  # K draft + K verify
         self.decode_steps += 2 * K  # K draft + K verify model steps
         self.spec_rounds += 1
         res = (np.asarray(verifs), np.asarray(bad), np.asarray(accepted),

@@ -10,19 +10,22 @@ import numpy as np
 import pytest
 
 from repro import codecs
+from repro.core import containers
 from repro.kernels import ops, ref
 from repro.kernels import packed_flash_decode as pfd
 
 
 def _pool(key, n_phys, bl, D, container, dtype):
-    """Random packed physical blocks (n_phys, bl, D)."""
+    """Random packed physical blocks: payload (n_phys, bl, payload
+    columns), fixed lanes or dense bit planes as the container packs."""
     ks = jax.random.split(key, 2)
     f = codecs.fields_for(container, dtype)
+    pack = ref.bitplane_pack_nd if f.dense else ref.sfp_pack_nd
     parts = []
     for k in ks:
         x = jax.random.normal(k, (n_phys * bl, D), jnp.float32).astype(dtype)
-        p, b = ref.sfp_pack_nd(x, f)
-        parts.append((p.reshape(n_phys, bl, D),
+        p, b = pack(x, f)
+        parts.append((p.reshape(n_phys, bl, -1),
                       b.reshape(n_phys, bl, D // 128)))
     (kp, kb), (vp, vb) = parts
     return (kp, kb, vp, vb), f
@@ -129,21 +132,48 @@ def test_ops_paged_dispatch_ref_vs_interpret():
                                rtol=1e-5, atol=1e-6)
 
 
-def test_trailing_trash_blocks_are_exact_noops():
-    """Extra logical blocks pointing at the trash block past a row's
-    position must not change the output by a single bit (the masked-block
-    recurrence contributes exactly zero)."""
-    B, KH, hd, bl = 1, 2, 64, 16
-    dtype = jnp.float32
-    (kp, kb, vp, vb), f = _pool(jax.random.PRNGKey(9), 5, bl, KH * hd,
-                                "sfp16", dtype)
-    q = jax.random.normal(jax.random.PRNGKey(10), (B, 1, KH, hd), dtype)
-    pos = jnp.array([bl - 2], jnp.int32)
-    short = jnp.array([[3]], jnp.int32)
-    long = jnp.array([[3, 0, 0, 0]], jnp.int32)
-    a = pfd.paged_flash_decode(q, kp, kb, vp, vb, short, pos, fields=f,
-                               interpret=True)
-    b = pfd.paged_flash_decode(q, kp, kb, vp, vb, long, pos, fields=f,
-                               interpret=True)
-    np.testing.assert_array_equal(np.asarray(a, np.float32),
-                                  np.asarray(b, np.float32))
+@pytest.mark.parametrize("draft", [False, True],
+                         ids=["full", "prefix_planes"])
+@pytest.mark.parametrize("container,dtype", [("sfp8", jnp.bfloat16),
+                                             ("sfp-m2e4", jnp.bfloat16),
+                                             ("sfp16", jnp.float32)])
+@pytest.mark.parametrize("dead", ["trash", "poison"])
+def test_trailing_trash_blocks_are_exact_noops(dead, container, dtype,
+                                               draft):
+    """Logical blocks past a row's position are skipped: neither fetched
+    nor expanded. With every dead table entry (and two extra trailing
+    columns) pointing at the trash block ("trash"), or at a block whose
+    payload and bases decode to Inf ("poison": Inf K/V would turn the
+    masked recurrence's p == 0 terms into NaN), the output must equal, bit
+    for bit, the short table's with its dead entries on the clean trash
+    block. Rows sit at pos 0, block_l - 1, block_l and L - 1 in one
+    batch; ``draft`` runs the speculative draft read mode."""
+    KH, hd, bl, nb = 2, 64, 16, 3
+    (kp, kb, vp, vb), f = _pool(jax.random.PRNGKey(9), 9, bl, KH * hd,
+                                container, dtype)
+    poison = 8  # bases 255 with dexp == 0 decode every value to +Inf
+    kp, vp = kp.at[poison].set(0), vp.at[poison].set(0)
+    kb, vb = kb.at[poison].set(255), vb.at[poison].set(255)
+    spec = containers.spec_for(jnp.dtype(dtype))
+    inf = ref.unpack_tile(kp[poison], kb[poison], f, spec, rows=bl, KH=KH,
+                          hd=hd)
+    assert not np.isfinite(np.asarray(inf)).any()
+
+    pos = jnp.array([0, bl - 1, bl, nb * bl - 1], jnp.int32)
+    live = [[1], [2], [3, 4], [5, 6, 7]]  # blocks holding slots <= pos
+    q = jax.random.normal(jax.random.PRNGKey(10), (len(live), 1, 4 * KH,
+                                                    hd)).astype(dtype)
+
+    def table(width, fill):
+        return jnp.array([row + [fill] * (width - len(row)) for row in live],
+                         jnp.int32)
+
+    planes = (max(f.payload_bits - 1, f.dexp_bits + 2) if draft else None)
+    run = functools.partial(pfd.paged_flash_decode, fields=f, softcap=30.0,
+                            interpret=True, prefix_planes=planes)
+    want = run(q, kp, kb, vp, vb, table(nb, 0), pos)
+    got = run(q, kp, kb, vp, vb,
+              table(nb + 2, poison if dead == "poison" else 0), pos)
+    assert np.isfinite(np.asarray(want, np.float32)).all()
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))

@@ -433,6 +433,39 @@ def test_burst_token_streams_identical_to_single_step():
     assert sK.stats.emitted_tokens == sum(news)
 
 
+def test_decode_kv_block_counter_counts_live_and_grid_blocks():
+    """serve_decode_kv_blocks_total counts, per decode step, the paged
+    kernel's live KV blocks (those holding a slot <= pos: sum over rows
+    of pos // block_l + 1) and its whole grid (slots x logical blocks);
+    a burst of K counts K steps at pos, pos+1, ... The count is made on
+    the host from the pos array the engine already holds: no transfer
+    to or from the device."""
+    cfg, model = _model("mistral-large-123b", "sfp8")
+    params = model.init(jax.random.PRNGKey(0))
+    ops.force_backend("ref")
+    try:
+        eng = engine.PagedEngine(model, params, max_slots=3, max_len=384)
+        bl = eng.block_l
+        assert eng.nmax == 3
+        fam = eng.obs.registry.counter("serve_decode_kv_blocks_total",
+                                       labels=("kind",))
+        toks = np.zeros(3, np.int32)
+        eng.decode(toks, np.array([0, bl + 2, 2 * bl + 44]))
+        # rows hold 1, 2 and 3 live blocks of 3
+        assert fam.total(kind="live") == 1 + 2 + 3
+        assert fam.total(kind="grid") == 3 * 3
+        eng.decode_burst(toks, np.array([0, bl - 1, 2 * bl - 1]), 3)
+        # pos + 0: blocks 1, 1, 2; pos + 1: 1, 2, 3; pos + 2: 1, 2, 3
+        assert fam.total(kind="live") == 6 + (4 + 6 + 6)
+        assert fam.total(kind="grid") == 9 + 3 * 9
+    finally:
+        ops.force_backend(None)
+    with jax.transfer_guard("disallow"):
+        eng._count_kv_blocks(np.array([0, 0, 3 * bl - 1]))
+    assert fam.total(kind="live") == 22 + 1 + 1 + 3
+    assert fam.total(kind="grid") == 36 + 9
+
+
 def test_burst_clamps_to_budget_and_capacity():
     """A burst never outruns max_len (hard) or the largest remaining
     token budget (efficiency): with max_new=3 everywhere, burst=32 must
@@ -646,6 +679,11 @@ def test_speculate_acceptance_bookkeeping():
     assert eng.decode_steps == s.decode_steps
     assert s.decode_steps % 2 == 0
     assert s.decode_steps <= 6 * s.spec_rounds
+    # the KV block counter charges both passes of every round
+    kv = sched.obs.registry.counter("serve_decode_kv_blocks_total",
+                                    labels=("kind",))
+    assert kv.total(kind="grid") == s.decode_steps * eng.max_slots * eng.nmax
+    assert 0 < kv.total(kind="live") <= kv.total(kind="grid")
     assert s.emitted_tokens == sum(len(v) for v in out.values())
 
 
