@@ -7,6 +7,7 @@ from repro.configs.base import (  # noqa: F401
     ArchConfig, ShapeConfig, SHAPES, LONG_CONTEXT_OK,
     cells_for, get, input_specs, names, reduced, register,
 )
+from repro.configs.granite_4_0_h_small import GRANITE_4_0_H_SMALL  # noqa: F401
 from repro.configs.gemma3_12b import GEMMA3_12B            # noqa: F401
 from repro.configs.mistral_large_123b import MISTRAL_LARGE_123B  # noqa: F401
 from repro.configs.gemma2_27b import GEMMA2_27B            # noqa: F401

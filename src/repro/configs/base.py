@@ -38,6 +38,8 @@ class ArchConfig:
     rope_theta: float = 10_000.0
     qk_norm: bool = False
     head_dim: Optional[int] = None
+    use_rope: bool = True        # False: no positional encoding (NoPE)
+    attn_scale: Optional[float] = None  # None: 1 / sqrt(head_dim)
     # mlp
     act: str = "silu"
     glu: bool = True
@@ -45,7 +47,12 @@ class ArchConfig:
     n_experts: int = 0
     top_k: int = 0
     d_ff_expert: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # training's; serving routes dropless
+    d_ff_shared: int = 0         # always-on shared expert width (0: none)
+    # Expert parallelism: this chip holds shard ``expert_shard`` of
+    # ``expert_shards`` equal slices of the experts; the router keeps all.
+    expert_shards: int = 1
+    expert_shard: int = 0
     # ssm (mamba2)
     ssm_state: int = 0
     ssm_head_dim: int = 64
@@ -53,13 +60,18 @@ class ArchConfig:
     ssm_chunk: int = 128
     ssm_groups: int = 1
     conv_width: int = 4
+    ssm_conv_bias: bool = False
+    ssd_mlp: bool = False        # SSD layers carry the MLP / MoE too
     # rglru (griffin)
     lru_width: Optional[int] = None
     # multimodal stub frontend
     prefix_tokens: int = 0       # precomputed frame/patch embeddings
     # misc
     tie_embeddings: bool = True
-    emb_scale: bool = False
+    emb_mult: Optional[float] = None  # embedding multiplier (gemma: sqrt(d))
+    residual_mult: float = 1.0   # every residual branch is scaled by it
+    logits_div: float = 1.0      # the logits are divided by it
+    norm_eps: float = 1e-6       # every RMSNorm, the gated one included
     dtype: str = "bfloat16"
     vocab_pad_multiple: int = 128
     source: str = ""
@@ -68,6 +80,14 @@ class ArchConfig:
     @property
     def head_dim_(self) -> int:
         return self.head_dim if self.head_dim else self.d_model // max(self.n_heads, 1)
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts // self.expert_shards
+
+    @property
+    def expert_lo(self) -> int:
+        return self.expert_shard * self.experts_held
 
     @property
     def padded_vocab(self) -> int:
@@ -111,15 +131,17 @@ class ArchConfig:
         per_kind = {}
         attn = d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd + self.n_heads * hd * d
         if self.is_moe:
-            mlp = d * self.n_experts + self.n_experts * (
+            mlp = d * self.n_experts + self.experts_held * (
                 (2 if self.glu else 1) * d * self.d_ff_expert + self.d_ff_expert * d)
+            mlp += 3 * d * self.d_ff_shared
         else:
             mlp = (2 if self.glu else 1) * d * self.d_ff + self.d_ff * d
         per_kind[GLOBAL] = attn + mlp + 2 * d
         per_kind[LOCAL] = attn + mlp + 2 * d
         di, N, H = self.d_inner, self.ssm_state, self.ssm_heads
         per_kind[SSD] = (d * (2 * di + 2 * self.ssm_groups * N + H) + di * d
-                         + 3 * H + 2 * d + di)
+                         + 3 * H + 2 * d + di
+                         + (mlp + d if self.ssd_mlp else 0))
         lw = self.lru_width_
         per_kind[RGLRU] = d * 2 * lw + lw * d + 2 * lw * lw + 3 * lw + 2 * d + mlp
         for i in range(self.n_layers):
@@ -220,6 +242,20 @@ def depth_cut(cfg: ArchConfig, n_layers: int) -> ArchConfig:
     return dataclasses.replace(cfg, n_layers=n_layers)
 
 
+def expert_share(cfg: ArchConfig, shard: int, shards: int) -> ArchConfig:
+    """One chip's share of an expert-parallel deployment in which
+    ``shards`` chips split every MoE layer's experts into equal slices and
+    this chip holds slice ``shard``. The router keeps all its outputs and
+    its experts per token; every width is kept."""
+    if not (cfg.is_moe and shards >= 1 and cfg.n_experts % shards == 0
+            and 0 <= shard < shards):
+        raise ValueError(
+            f"{cfg.name}: an expert share needs 0 <= shard < shards and "
+            f"shards dividing its {cfg.n_experts} experts, got "
+            f"{shard}/{shards}")
+    return dataclasses.replace(cfg, expert_shards=shards, expert_shard=shard)
+
+
 def reduced(cfg: ArchConfig, *, n_layers: Optional[int] = None,
             d_model: int = 128, seq: int = 64) -> ArchConfig:
     """A tiny same-family variant for CPU smoke tests."""
@@ -241,6 +277,10 @@ def reduced(cfg: ArchConfig, *, n_layers: Optional[int] = None,
     )
     if cfg.is_moe:
         changes.update(n_experts=4, top_k=2, d_ff_expert=d_model * 2)
+    if cfg.d_ff_shared:
+        changes.update(d_ff_shared=d_model * 2)
+    if cfg.emb_mult == cfg.d_model ** 0.5:  # a sqrt(d) normaliser follows d
+        changes.update(emb_mult=d_model ** 0.5)
     if SSD in period:
         changes.update(ssm_state=16, ssm_head_dim=32, ssm_chunk=16)
     if RGLRU in period:

@@ -19,6 +19,6 @@ GEMMA2_2B = register(ArchConfig(
     attn_softcap=50.0,
     final_softcap=30.0,
     act="gelu",
-    emb_scale=True,
+    emb_mult=2304 ** 0.5,
     source="arXiv:2408.00118 (Gemma 2); assignment spec",
 ))

@@ -19,6 +19,6 @@ GEMMA3_12B = register(ArchConfig(
     rope_theta=1_000_000.0,
     qk_norm=True,
     act="gelu",
-    emb_scale=True,
+    emb_mult=3840 ** 0.5,
     source="hf:google/gemma-3 family; assignment spec",
 ))

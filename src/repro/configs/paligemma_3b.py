@@ -17,7 +17,7 @@ PALIGEMMA_3B = register(ArchConfig(
     vocab=257_216,
     period=(GLOBAL,),
     act="gelu",
-    emb_scale=True,
+    emb_mult=2048 ** 0.5,
     prefix_tokens=256,
     source="arXiv:2407.07726 (PaliGemma); assignment spec",
 ))

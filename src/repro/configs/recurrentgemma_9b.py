@@ -21,6 +21,6 @@ RECURRENTGEMMA_9B = register(ArchConfig(
     lru_width=4096,
     conv_width=4,
     act="gelu",
-    emb_scale=True,
+    emb_mult=4096 ** 0.5,
     source="arXiv:2402.19427 (Griffin/RecurrentGemma); assignment spec",
 ))

@@ -45,7 +45,7 @@ import numpy as np
 
 from repro import configs
 from repro import obs as obs_mod
-from repro.configs.base import depth_cut, reduced
+from repro.configs.base import depth_cut, expert_share, reduced
 from repro.launch.args import container_name
 from repro.launch.cache import enable_compile_cache
 from repro.models.model import DecoderModel
@@ -64,6 +64,8 @@ def build_model(args, params=None):
         cfg = reduced(cfg, n_layers=max(2 * len(cfg.period), 4), d_model=256)
     if args.layers:
         cfg = depth_cut(cfg, args.layers)
+    if args.expert_share:
+        cfg = expert_share(cfg, *args.expert_share)
     container = args.kv_container
     if args.policy_ckpt:
         container = precision.container_from_checkpoint(args.policy_ckpt)
@@ -196,6 +198,8 @@ def run_trace(args, built=None, on_step=None):
     n = max(1, len(reqs))
     kv_blocks = sched.obs.registry.counter("serve_decode_kv_blocks_total",
                                            labels=("kind",))
+    moe = sched.obs.registry.counter("serve_moe_assignments_total",
+                                     labels=("kind",))
     report = {
         "arch": cfg.name, "container": container,
         "requests": len(reqs), "emitted_tokens": total,
@@ -208,6 +212,14 @@ def run_trace(args, built=None, on_step=None):
             kv_blocks.total(kind="live")
             / max(1.0, kv_blocks.total(kind="grid")), 4),
         "preemptions": s.preemptions,
+        # Dropless MoE routing (engine counters; every slot a step runs,
+        # idle ones included): assignments to the experts held here, and
+        # the most one held expert got in one layer call.
+        "moe_held_assignments": int(moe.total(kind="held")),
+        "moe_held_share": round(moe.total(kind="held")
+                                / max(1.0, moe.total(kind="routed")), 4),
+        "moe_max_expert_load": int(sched.obs.registry.gauge(
+            "serve_moe_max_expert_load").value),
         "mean_ttft_steps": round(float(np.mean(list(ttft.values()))), 2)
         if ttft else None,
         # Wall-clock latency percentiles from the obs histograms
@@ -255,6 +267,14 @@ def run_trace(args, built=None, on_step=None):
     return report, sched
 
 
+def _share(value: str):
+    try:
+        shard, shards = (int(v) for v in value.split("/"))
+    except ValueError:
+        raise argparse.ArgumentTypeError(f"expected I/N, got {value!r}")
+    return shard, shards
+
+
 def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
@@ -264,6 +284,11 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--layers", type=int, default=None,
                     help="keep every width and cut the depth to N layers "
                     "(whole periods; configs.base.depth_cut)")
+    ap.add_argument("--expert-share", default=None, type=_share,
+                    metavar="I/N",
+                    help="hold slice I of N equal slices of every MoE "
+                    "layer's experts (expert parallelism over N chips; "
+                    "the router keeps every expert)")
     ap.add_argument("--kv-container", default=None, type=container_name,
                     help="registry codec for the packed KV cache (sfp8, "
                     "sfp16, dense sfp-m2e4, ...); None = raw bf16 cache")

@@ -60,7 +60,14 @@ def _qkv_specs(cfg: ArchConfig, batch_size: int):
 def _project_qkv(params, h, cfg: ArchConfig, positions):
     B, S, _ = h.shape
     hd, H, KH = cfg.head_dim_, cfg.n_heads, cfg.n_kv_heads
-    q = (h @ params["wq"]).reshape(B, S, H, hd)
+    if cfg.attn_scale is None:
+        q = h @ params["wq"]
+    else:
+        # Every attention path scales scores by 1/sqrt(hd); a configured
+        # scale is folded into q in f32, before q's one rounding.
+        q = (jnp.dot(h, params["wq"], preferred_element_type=jnp.float32)
+             * (cfg.attn_scale * hd ** 0.5)).astype(h.dtype)
+    q = q.reshape(B, S, H, hd)
     k = (h @ params["wk"]).reshape(B, S, KH, hd)
     v = (h @ params["wv"]).reshape(B, S, KH, hd)
     q_spec, kv_spec, _ = _qkv_specs(cfg, B)
@@ -69,10 +76,11 @@ def _project_qkv(params, h, cfg: ArchConfig, positions):
         k = shd.hint(k, *kv_spec)
         v = shd.hint(v, *kv_spec)
     if cfg.qk_norm:
-        q = common.rmsnorm(params["q_norm"], q)
-        k = common.rmsnorm(params["k_norm"], k)
-    q = common.rope(q, positions, cfg.rope_theta)
-    k = common.rope(k, positions, cfg.rope_theta)
+        q = common.rmsnorm(params["q_norm"], q, cfg.norm_eps)
+        k = common.rmsnorm(params["k_norm"], k, cfg.norm_eps)
+    if cfg.use_rope:
+        q = common.rope(q, positions, cfg.rope_theta)
+        k = common.rope(k, positions, cfg.rope_theta)
     if q_spec is not None:
         q = shd.hint(q, *q_spec)
         k = shd.hint(k, *kv_spec)

@@ -142,13 +142,16 @@ def embed(params, tokens: jax.Array, scale: Optional[float] = None,
 
 def unembed(params, h: jax.Array, *, tied: bool,
             softcap: Optional[float] = None,
-            valid_vocab: Optional[int] = None) -> jax.Array:
+            valid_vocab: Optional[int] = None,
+            div: float = 1.0) -> jax.Array:
     table = params["embed"]["table"] if tied else params["head"]
     if tied:
         logits = jnp.einsum("bsd,vd->bsv", h, table)
     else:
         logits = jnp.einsum("bsd,dv->bsv", h, table)
     logits = logits.astype(jnp.float32)
+    if div != 1.0:
+        logits = logits / div
     if softcap is not None:
         logits = softcap * jnp.tanh(logits / softcap)
     if valid_vocab is not None and valid_vocab < logits.shape[-1]:

@@ -25,7 +25,7 @@ def ssd_init(p: common.ParamFactory, cfg: ArchConfig):
     d, di = cfg.d_model, cfg.d_inner
     G, N, H = cfg.ssm_groups, cfg.ssm_state, cfg.ssm_heads
     cw = cfg.conv_width
-    return {
+    params = {
         "w_x": p((d, di), ("embed", "ssm_inner")),
         "w_z": p((d, di), ("embed", "ssm_inner")),
         "w_B": p((d, G * N), ("embed", "state")),
@@ -40,6 +40,11 @@ def ssd_init(p: common.ParamFactory, cfg: ArchConfig):
         "norm": common.rmsnorm_init(p, di, axis="ssm_inner"),
         "w_out": p((di, d), ("ssm_inner", "embed")),
     }
+    if cfg.ssm_conv_bias:
+        # one row over the channels of x, B and C, in that order
+        params["conv_bias"] = p((1, di + 2 * G * N), ("conv", None),
+                                init="zeros")
+    return params
 
 
 def _causal_conv(x: jax.Array, w: jax.Array, state=None):
@@ -82,6 +87,12 @@ def _projections(params, h, cfg: ArchConfig, conv_state=None,
                           conv_state["B"] if conv_state else None)
     Cp, sC = _causal_conv(Cp, params["conv_C"],
                           conv_state["C"] if conv_state else None)
+    if cfg.ssm_conv_bias:
+        b = params["conv_bias"][0].astype(h.dtype)
+        di, gn = x.shape[-1], Bp.shape[-1]
+        x = x + b[:di]
+        Bp = Bp + b[di:di + gn]
+        Cp = Cp + b[di + gn:]
     x = jax.nn.silu(x.astype(jnp.float32)).astype(h.dtype)
     Bp = jax.nn.silu(Bp.astype(jnp.float32)).astype(h.dtype)
     Cp = jax.nn.silu(Cp.astype(jnp.float32)).astype(h.dtype)
@@ -172,7 +183,8 @@ def ssd_forward(params, h: jax.Array, cfg: ArchConfig,
         y = y[:, :S_orig]
 
     y = common.rmsnorm(params["norm"], y * jax.nn.silu(z.astype(jnp.float32)
-                                                       ).astype(h.dtype))
+                                                       ).astype(h.dtype),
+                       cfg.norm_eps)
     out = y @ params["w_out"]
     if return_cache:
         cache = SSDCache(conv_x=raw_tail["x"], conv_B=raw_tail["B"],
@@ -234,7 +246,8 @@ def ssd_decode(params, h_tok: jax.Array, cache: SSDCache, cfg: ArchConfig
     y = y + xf * params["D"][None, :, None]
     y = y.reshape(B, 1, H * P).astype(h_tok.dtype)
     y = common.rmsnorm(params["norm"],
-                       y * jax.nn.silu(z.astype(jnp.float32)).astype(h_tok.dtype))
+                       y * jax.nn.silu(z.astype(jnp.float32)).astype(h_tok.dtype),
+                       cfg.norm_eps)
     out = y @ params["w_out"]
     return out, SSDCache(conv_x=new_conv["x"], conv_B=new_conv["B"],
                          conv_C=new_conv["C"], state=state)

@@ -131,7 +131,8 @@ class DecoderModel:
             slot["attn"] = attention.attn_init(p, cfg)
         elif kind == SSD:
             slot["ssd"] = mamba2.ssd_init(p, cfg)
-            return slot  # mamba2 blocks carry no separate MLP
+            if not cfg.ssd_mlp:
+                return slot  # mamba2 blocks carry no separate MLP
         elif kind == RGLRU:
             slot["rglru"] = rglru.rglru_init(p, cfg)
         else:  # pragma: no cover
@@ -226,27 +227,55 @@ class DecoderModel:
         aux = _zero_moe_aux()
         extras_loss = jnp.zeros((), jnp.float32)
 
-        hn = common.rmsnorm(sp["pre_norm"], h)
+        hn = common.rmsnorm(sp["pre_norm"], h, cfg.norm_eps)
         if kind in (GLOBAL, LOCAL):
-            h = h + attention.attention_train(
+            h = self._res(h, attention.attention_train(
                 sp["attn"], hn, cfg, kind=kind, positions=positions,
-                prefix_len=prefix_len)
+                prefix_len=prefix_len))
         elif kind == SSD:
-            h = h + mamba2.ssd_forward(sp["ssd"], hn, cfg)
-            return h, extras_loss, aux
+            h = self._res(h, mamba2.ssd_forward(sp["ssd"], hn, cfg))
+            if not cfg.ssd_mlp:
+                return h, extras_loss, aux
         elif kind == RGLRU:
             h = h + rglru.rglru_forward(sp["rglru"], hn, cfg)
 
-        hm = common.rmsnorm(sp["mlp_norm"], h)
-        if cfg.is_moe:
-            out, moe_aux = moe.moe_forward(sp["moe"], hm, cfg)
-            h = h + out
+        h, moe_aux, _ = self._ffn(sp, h, dropless=False)
+        if moe_aux is not None:
             aux = moe_aux
             extras_loss = (MOE_LB_COEF * moe_aux["moe_lb_loss"]
                            + MOE_Z_COEF * moe_aux["moe_z_loss"])
-        else:
-            h = h + common.mlp(sp["mlp"], hm, cfg.act, cfg.glu)
         return h, extras_loss, aux
+
+    def _res(self, h, out):
+        """The residual add, the branch scaled by ``residual_mult``."""
+        r = self.cfg.residual_mult
+        return h + out if r == 1.0 else h + out * jnp.asarray(r, out.dtype)
+
+    def _ffn(self, sp, h, *, dropless: bool):
+        """The layer's MLP or MoE on the residual stream: (h, aux losses
+        or None, dropless MoE counters or None). Serving routes dropless;
+        training drops what passes ``capacity_factor``."""
+        cfg = self.cfg
+        hm = common.rmsnorm(sp["mlp_norm"], h, cfg.norm_eps)
+        if not cfg.is_moe:
+            return (self._res(h, common.mlp(sp["mlp"], hm, cfg.act, cfg.glu)),
+                    None, None)
+        if dropless:
+            out, stats = moe.moe_dropless(sp["moe"], hm, cfg)
+            return self._res(h, out), None, stats
+        out, aux = moe.moe_forward(sp["moe"], hm, cfg)
+        return self._res(h, out), aux, None
+
+    def _embed(self, params, tokens):
+        return common.embed(params["embed"], tokens, self.cfg.emb_mult,
+                            mesh=self.mesh)
+
+    def _logits(self, params, h):
+        cfg = self.cfg
+        h = common.rmsnorm(params["final_norm"], h, cfg.norm_eps)
+        return common.unembed(params, h, tied=cfg.tie_embeddings,
+                              softcap=cfg.final_softcap,
+                              valid_vocab=cfg.vocab, div=cfg.logits_div)
 
     # ------------------------------------------------------------------
     # Stash codec (compress/decompress at period boundaries)
@@ -317,8 +346,7 @@ class DecoderModel:
         B, S = tokens.shape
         P = cfg.prefix_tokens if cond_embeddings is not None else 0
 
-        scale = (cfg.d_model ** 0.5) if cfg.emb_scale else None
-        h = common.embed(params["embed"], tokens, scale, mesh=self.mesh)
+        h = self._embed(params, tokens)
         if P:
             h = jnp.concatenate(
                 [cond_embeddings.astype(h.dtype), h], axis=1)
@@ -388,12 +416,9 @@ class DecoderModel:
                 key=jax.random.fold_in(run.key, 2000 + i))
             extras = extras + eloss
 
-        h = common.rmsnorm(params["final_norm"], h)
         if P:
             h = h[:, P:]
-        logits = common.unembed(params, h, tied=cfg.tie_embeddings,
-                                softcap=cfg.final_softcap,
-                                valid_vocab=cfg.vocab)
+        logits = self._logits(params, h)
         metrics = {"moe_aux_loss": extras}
         for k in ("moe_lb_loss", "moe_z_loss", "moe_drop_frac"):
             metrics[k] = aux[k].mean() if cfg.is_moe else jnp.zeros((), jnp.float32)
@@ -450,8 +475,10 @@ class DecoderModel:
 
     def _decode_slot(self, slot_params, h, slot_cache, pos, kind,
                      tables=None, prefix_planes=None):
+        """One layer of a decode step: (h, its new cache, its dropless MoE
+        counters or None)."""
         cfg = self.cfg
-        hn = common.rmsnorm(slot_params["pre_norm"], h)
+        hn = common.rmsnorm(slot_params["pre_norm"], h, cfg.norm_eps)
         if kind in (GLOBAL, LOCAL):
             if tables is not None and kind == GLOBAL:
                 # Paged pool: blocks gathered through the block table
@@ -470,31 +497,31 @@ class DecoderModel:
             else:
                 out, new_cache = attention.attention_decode(
                     slot_params["attn"], hn, slot_cache, pos, cfg, kind=kind)
-            h = h + out
+            h = self._res(h, out)
         elif kind == SSD:
             out, new_cache = mamba2.ssd_decode(slot_params["ssd"], hn,
                                                slot_cache, cfg)
-            return h + out, new_cache
+            h = self._res(h, out)
+            if not cfg.ssd_mlp:
+                return h, new_cache, None
         else:
             out, new_cache = rglru.rglru_decode(slot_params["rglru"], hn,
                                                 slot_cache, cfg)
             h = h + out
-        hm = common.rmsnorm(slot_params["mlp_norm"], h)
-        if cfg.is_moe:
-            h = h + moe.moe_decode(slot_params["moe"], hm, cfg)
-        else:
-            h = h + common.mlp(slot_params["mlp"], hm, cfg.act, cfg.glu)
-        return h, new_cache
+        h, _, stats = self._ffn(slot_params, h, dropless=True)
+        return h, new_cache, stats
 
     def _prefill_slot(self, slot_params, h, kind, *, positions, prefix_len,
                       max_len):
+        """One layer of a prefill: (h, its cache, its dropless MoE
+        counters or None)."""
         cfg = self.cfg
-        hn = common.rmsnorm(slot_params["pre_norm"], h)
+        hn = common.rmsnorm(slot_params["pre_norm"], h, cfg.norm_eps)
         if kind in (GLOBAL, LOCAL):
             out, (k, v) = attention.attention_train(
                 slot_params["attn"], hn, cfg, kind=kind, positions=positions,
                 prefix_len=prefix_len, return_kv=True)
-            h = h + out
+            h = self._res(h, out)
             if self.kv_container is not None:
                 # Packed caches round up to fused-kernel block multiples;
                 # prefill must produce the same allocation as init_cache.
@@ -515,22 +542,55 @@ class DecoderModel:
         elif kind == SSD:
             out, new_cache = mamba2.ssd_forward(slot_params["ssd"], hn, cfg,
                                                 return_cache=True)
-            return h + out, new_cache
+            h = self._res(h, out)
+            if not cfg.ssd_mlp:
+                return h, new_cache, None
         else:
             out, new_cache = rglru.rglru_forward(slot_params["rglru"], hn,
                                                  cfg, return_cache=True)
             h = h + out
-        hm = common.rmsnorm(slot_params["mlp_norm"], h)
-        if cfg.is_moe:
-            out, _aux = moe.moe_forward(slot_params["moe"], hm, cfg)
-            h = h + out
-        else:
-            h = h + common.mlp(slot_params["mlp"], hm, cfg.act, cfg.glu)
-        return h, new_cache
+        h, _, stats = self._ffn(slot_params, h, dropless=True)
+        return h, new_cache, stats
+
+    def _layers(self, slot_fn, h, params, cache=None):
+        """Run ``slot_fn(slot_params, h, slot_cache, kind) -> (h, cache,
+        stats)`` over every layer: the periods as one scan, then the
+        remainder. Returns (h, caches, stats), stats being each dropless
+        MoE counter stacked over the MoE layers in order ({} without)."""
+        cfg = self.cfg
+
+        def run(h, p, c, kinds):
+            caches, stats = {}, []
+            for i, kind in enumerate(kinds):
+                h, caches[f"slot{i}"], st = slot_fn(
+                    p[f"slot{i}"], h, None if c is None else c[f"slot{i}"],
+                    kind)
+                if st is not None:
+                    stats.append(st)
+            return h, caches, {k: jnp.stack([st[k] for st in stats])
+                               for k in (stats[0] if stats else {})}
+
+        def period_fn(h, x):
+            h, caches, stats = run(h, x[0], x[1], cfg.period)
+            return h, (caches, stats)
+
+        xs = (params["periods"], None if cache is None else cache["periods"])
+        h, (period_caches, stats) = jax.lax.scan(period_fn, h, xs)
+        stats = {k: v.reshape(-1) for k, v in stats.items()}
+        out = {"periods": period_caches}
+        if cfg.remainder:
+            h, out["rem"], rem_stats = run(
+                h, params["rem"], None if cache is None else cache["rem"],
+                cfg.remainder)
+            stats = {k: jnp.concatenate([stats[k], rem_stats[k]])
+                     for k in stats}
+        return h, out, stats
 
     def prefill(self, params, tokens: jax.Array, max_len: int,
-                cond_embeddings: Optional[jax.Array] = None):
-        """Process a full prompt, returning (last-position logits, cache).
+                cond_embeddings: Optional[jax.Array] = None,
+                stats: bool = False):
+        """Process a full prompt, returning (last-position logits, cache),
+        and with ``stats`` the dropless MoE counters per MoE layer too.
 
         ``max_len`` sizes the global-attention KV cache (prompt + decode
         budget). The prompt (with any multimodal prefix) must fit max_len.
@@ -539,43 +599,28 @@ class DecoderModel:
         cfg = self.cfg
         B, S = tokens.shape
         P = cfg.prefix_tokens if cond_embeddings is not None else 0
-        scale = (cfg.d_model ** 0.5) if cfg.emb_scale else None
-        h = common.embed(params["embed"], tokens, scale, mesh=self.mesh)
+        h = self._embed(params, tokens)
         if P:
             h = jnp.concatenate([cond_embeddings.astype(h.dtype), h], axis=1)
         positions = jnp.arange(h.shape[1])
         max_len = max(max_len, h.shape[1])  # prefix tokens extend the cache
 
-        def period_fn(h, p):
-            caches = {}
-            for i, kind in enumerate(cfg.period):
-                h, c = self._prefill_slot(p[f"slot{i}"], h, kind,
-                                          positions=positions, prefix_len=P,
-                                          max_len=max_len)
-                caches[f"slot{i}"] = c
-            return h, caches
+        def slot_fn(p, h, _c, kind):
+            return self._prefill_slot(p, h, kind, positions=positions,
+                                      prefix_len=P, max_len=max_len)
 
-        h, period_caches = jax.lax.scan(period_fn, h, params["periods"])
-        cache = {"periods": period_caches}
-        if cfg.remainder:
-            cache["rem"] = {}
-            for i, kind in enumerate(cfg.remainder):
-                h, c = self._prefill_slot(params["rem"][f"slot{i}"], h, kind,
-                                          positions=positions, prefix_len=P,
-                                          max_len=max_len)
-                cache["rem"][f"slot{i}"] = c
-        h = common.rmsnorm(params["final_norm"], h)
-        logits = common.unembed(params, h[:, -1:], tied=cfg.tie_embeddings,
-                                softcap=cfg.final_softcap,
-                                valid_vocab=cfg.vocab)
-        return logits, cache
+        h, cache, st = self._layers(slot_fn, h, params)
+        logits = self._logits(params, h[:, -1:])
+        return (logits, cache, st) if stats else (logits, cache)
 
     def decode_step(self, params, cache, token: jax.Array, pos: jax.Array,
                     tables: Optional[jax.Array] = None,
-                    prefix_planes: Optional[int] = None
-                    ) -> Tuple[jax.Array, Any]:
+                    prefix_planes: Optional[int] = None,
+                    stats: bool = False):
         """One decode step. token: (B, 1) int32; pos: scalar int32 absolute
-        position (prefix + generated so far). Returns (logits (B, 1, V), cache).
+        position (prefix + generated so far). Returns (logits (B, 1, V),
+        cache), and with ``stats`` the dropless MoE counters per MoE layer
+        ({} for a model without experts).
 
         With ``tables`` (B, nb) this is the continuous-batching paged
         step: ``pos`` becomes (B,) per-slot positions (idle slots carry
@@ -593,42 +638,21 @@ class DecoderModel:
         assert prefix_planes is None or self.kv_container is not None, \
             "prefix_planes (draft reads) needs a packed kv_container"
         shd.set_active_mesh(self.mesh, self.rules)
-        cfg = self.cfg
-        scale = (cfg.d_model ** 0.5) if cfg.emb_scale else None
-        h = common.embed(params["embed"], token, scale, mesh=self.mesh)
+        h = self._embed(params, token)
 
-        def period_fn(h, x):
-            p, c = x
-            new_c = {}
-            for i, kind in enumerate(cfg.period):
-                h, nc = self._decode_slot(p[f"slot{i}"], h, c[f"slot{i}"],
-                                          pos, kind, tables=tables,
-                                          prefix_planes=prefix_planes)
-                new_c[f"slot{i}"] = nc
-            return h, new_c
+        def slot_fn(p, h, c, kind):
+            return self._decode_slot(p, h, c, pos, kind, tables=tables,
+                                     prefix_planes=prefix_planes)
 
-        h, new_periods = jax.lax.scan(
-            period_fn, h, (params["periods"], cache["periods"]))
-        new_cache = {"periods": new_periods}
-        if cfg.remainder:
-            new_cache["rem"] = {}
-            for i, kind in enumerate(cfg.remainder):
-                h, nc = self._decode_slot(params["rem"][f"slot{i}"], h,
-                                          cache["rem"][f"slot{i}"], pos,
-                                          kind, tables=tables,
-                                          prefix_planes=prefix_planes)
-                new_cache["rem"][f"slot{i}"] = nc
-        h = common.rmsnorm(params["final_norm"], h)
-        logits = common.unembed(params, h, tied=cfg.tie_embeddings,
-                                softcap=cfg.final_softcap,
-                                valid_vocab=cfg.vocab)
-        return logits, new_cache
+        h, new_cache, st = self._layers(slot_fn, h, params, cache)
+        logits = self._logits(params, h)
+        return (logits, new_cache, st) if stats else (logits, new_cache)
 
     def decode_step_paged(self, params, cache, token: jax.Array,
                           pos: jax.Array, tables: jax.Array,
-                          prefix_planes: Optional[int] = None
-                          ) -> Tuple[jax.Array, Any]:
+                          prefix_planes: Optional[int] = None,
+                          stats: bool = False):
         """Paged decode step (see ``decode_step`` with ``tables``)."""
         assert self.kv_container is not None, "paged decode needs a codec"
         return self.decode_step(params, cache, token, pos, tables=tables,
-                                prefix_planes=prefix_planes)
+                                prefix_planes=prefix_planes, stats=stats)

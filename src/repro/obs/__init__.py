@@ -25,8 +25,8 @@ from typing import Any
 from repro.obs.registry import (EventLog, MetricsRegistry,  # noqa: F401
                                 log_buckets)
 from repro.obs.timeline import PrecisionTimeline  # noqa: F401
-from repro.obs.trace import (SpanTracer, profiled_spans,  # noqa: F401
-                             span)
+from repro.obs.trace import (SpanTracer, note,  # noqa: F401
+                             profiled_notes, profiled_spans, span)
 
 
 class Obs:

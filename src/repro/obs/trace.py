@@ -137,6 +137,26 @@ def span(name: str, tracer: SpanTracer | None = None) -> Iterator[None]:
             tracer.end(opened)
 
 
+# (name, time_s, values) noted while a profiler recorded; bounded too.
+_NOTED: deque[tuple[str, float, tuple]] = deque(maxlen=1 << 16)
+
+
+def note(name: str, values) -> None:
+    """Keep host ``values`` under ``name``, stamped on
+    ``time.perf_counter``, for ``profiled_notes()`` while a profiler
+    records; nothing otherwise. Host code only, after the values were
+    pulled from the device."""
+    if TraceAnnotation.is_enabled():
+        _NOTED.append((name, time.perf_counter(), tuple(values)))
+
+
+def profiled_notes(lo: float = float("-inf"),
+                   hi: float = float("inf")) -> list[tuple[str, float, tuple]]:
+    """Notes taken while a profiler recorded, stamped inside
+    [``lo``, ``hi``], oldest first."""
+    return [n for n in _NOTED if lo <= n[1] <= hi]
+
+
 def profiled_spans(lo: float = float("-inf"),
                    hi: float = float("inf")) -> list[tuple[str, float, float]]:
     """Spans closed while a profiler recorded that lie wholly inside

@@ -20,6 +20,7 @@ become exact all-reduces), recurrent-state widths over `model`.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Any, Dict, Optional, Tuple
 
@@ -310,6 +311,29 @@ class PagedEngine:
         fam.labels(kind="grid").inc(
             passes * steps * self.max_slots * self.nmax)
 
+    def _record_moe(self, moe: Dict[str, np.ndarray], tokens: int) -> None:
+        """Count one model call's dropless MoE routing (``moe``: host
+        arrays of ``held`` and ``max_load``, one entry per MoE layer;
+        ``tokens`` the rows the call ran, idle slots included): the
+        assignments, the held ones, the largest load on one held expert so
+        far. While a profiler records, each layer's held count is also
+        noted for the benchmark's reader of the grouped matmul."""
+        if not moe:
+            return
+        held = np.asarray(moe["held"]).reshape(-1)
+        fam = self.obs.registry.counter(
+            "serve_moe_assignments_total",
+            "token-expert assignments of the routed MoE layers: all of "
+            "them (routed) and those on experts held here (held)",
+            labels=("kind",))
+        fam.labels(kind="routed").inc(tokens * self.cfg.top_k * held.size)
+        fam.labels(kind="held").inc(int(held.sum()))
+        load = self.obs.registry.gauge(
+            "serve_moe_max_expert_load",
+            "most assignments one held expert got in one layer call")
+        load.set(max(load.value, int(np.max(moe["max_load"]))))
+        obs_mod.note("serve.moe_held", held.tolist())
+
     # -- device memory ---------------------------------------------------
 
     def _slot_mem(self, kind: str):
@@ -563,10 +587,11 @@ class PagedEngine:
         if narrow and self._requant is None:
             raise ValueError("narrow prefill needs degraded_container")
         prefill = compiled(
-            self.model, ("prefill", self.max_len),
-            lambda: jax.jit(make_prefill_step(self.model, self.max_len)))
-        logits, pref_cache = prefill(self.params, jnp.asarray(prompt)[None],
-                                     None)
+            self.model, ("paged_prefill", self.max_len),
+            lambda: jax.jit(functools.partial(
+                self.model.prefill, max_len=self.max_len, stats=True)))
+        logits, pref_cache, moe = prefill(self.params,
+                                          jnp.asarray(prompt)[None])
         if narrow:
             pref_cache = self._requant(pref_cache)
         ids_np = self.pool.tables[slot]
@@ -576,7 +601,9 @@ class PagedEngine:
         if self.integrity:
             self.refresh_checksums([p for p in ids_np
                                     if p != _pool.TRASH_BLOCK])
-        tok = int(jnp.argmax(logits[0, -1]))
+        tok, moe = jax.device_get((jnp.argmax(logits[0, -1]), moe))
+        self._record_moe(moe, prompt.size)
+        tok = int(tok)
         self._observe("serve_prefill_seconds",
                       "prefill-into-slot wall time (incl. scatter)",
                       time.perf_counter() - t0)
@@ -585,14 +612,14 @@ class PagedEngine:
     # -- decode ----------------------------------------------------------
 
     def _step_fn(self, params, mem, tables, toks, pos):
-        logits, mem = self.model.decode_step_paged(params, mem, toks, pos,
-                                                   tables)
+        logits, mem, moe = self.model.decode_step_paged(
+            params, mem, toks, pos, tables, stats=True)
         nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
         # NaN/Inf logit guard: a per-slot "bad" flag computed inside the
         # jitted step (free — logits are already on device). The scheduler
         # quarantines flagged slots instead of streaming garbage.
         bad = ~jnp.all(jnp.isfinite(logits), axis=(1, 2))
-        return nxt, bad, mem
+        return nxt, bad, moe, mem
 
     def decode(self, toks: np.ndarray, pos: np.ndarray):
         """One batched decode step over every slot.
@@ -604,14 +631,16 @@ class PagedEngine:
         """
         t0 = time.perf_counter()
         tables = jnp.asarray(self.pool.tables)
-        nxt, bad, self.mem = self._step(
+        nxt, bad, moe, self.mem = self._step(
             self.params, self.mem, tables,
             jnp.asarray(toks, jnp.int32)[:, None],
             jnp.asarray(pos, jnp.int32))
         # Counted while the step runs (dispatch is asynchronous).
         self._count_kv_blocks(pos)
         self.decode_steps += 1
-        out = np.asarray(nxt), np.asarray(bad)
+        nxt, bad, moe = jax.device_get((nxt, bad, moe))
+        self._record_moe(moe, self.max_slots)
+        out = nxt, bad
         self._observe("serve_decode_seconds",
                       "decode dispatch wall time (whole burst)",
                       time.perf_counter() - t0)
@@ -631,15 +660,15 @@ class PagedEngine:
         def burst(params, mem, tables, toks, pos):
             def step(carry, i):
                 tok, mem = carry
-                logits, mem = self.model.decode_step_paged(
-                    params, mem, tok, pos + i, tables)
+                logits, mem, moe = self.model.decode_step_paged(
+                    params, mem, tok, pos + i, tables, stats=True)
                 nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
                 bad = ~jnp.all(jnp.isfinite(logits), axis=(1, 2))
-                return (nxt[:, None], mem), (nxt, bad)
+                return (nxt[:, None], mem), (nxt, bad, moe)
 
-            (_, mem), (out, bad) = jax.lax.scan(
+            (_, mem), (out, bad, moe) = jax.lax.scan(
                 step, (toks, mem), jnp.arange(K, dtype=jnp.int32))
-            return out, bad, mem  # out/bad: (K, max_slots)
+            return out, bad, moe, mem  # out/bad: (K, max_slots)
 
         return jax.jit(burst, donate_argnums=(1,))
 
@@ -666,12 +695,16 @@ class PagedEngine:
             fn = self._bursts[K] = self._make_burst(K)
         t0 = time.perf_counter()
         tables = jnp.asarray(self.pool.tables)
-        out, bad, self.mem = fn(self.params, self.mem, tables,
-                                jnp.asarray(toks, jnp.int32)[:, None],
-                                jnp.asarray(pos, jnp.int32))
+        out, bad, moe, self.mem = fn(self.params, self.mem, tables,
+                                     jnp.asarray(toks, jnp.int32)[:, None],
+                                     jnp.asarray(pos, jnp.int32))
         self._count_kv_blocks(pos, K)
         self.decode_steps += K
-        res = np.asarray(out), np.asarray(bad)
+        out, bad, moe = jax.device_get((out, bad, moe))
+        for i in range(K):
+            self._record_moe({k: v[i] for k, v in moe.items()},
+                             self.max_slots)
+        res = out, bad
         self._observe("serve_decode_seconds",
                       "decode dispatch wall time (whole burst)",
                       time.perf_counter() - t0)

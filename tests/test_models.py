@@ -173,8 +173,10 @@ def test_moe_decode_matches_forward_when_no_drops():
     p = common.ParamFactory("params", jax.random.PRNGKey(0), jnp.float32)
     params = moe.moe_init(p, cfg)
     h = jax.random.normal(jax.random.PRNGKey(2), (4, 1, cfg.d_model)) * 0.5
-    dec = moe.moe_decode(params, h, cfg)
-    fwd, _ = moe.moe_forward(params, h, cfg)
+    # decode routes dropless; a capacity that drops nothing must agree
+    dec, _ = moe.moe_dropless(params, h, cfg)
+    fwd, aux = moe.moe_forward(params, h, cfg)
+    assert float(aux["moe_drop_frac"]) == 0.0
     np.testing.assert_allclose(np.asarray(dec), np.asarray(fwd),
                                atol=1e-4, rtol=1e-4)
 
@@ -185,3 +187,14 @@ def test_ring_pack_kv_layout():
     kp, _ = attention.ring_pack_kv(k, k, L)
     # slot s holds latest pos p <= 9 with p % 4 == s: [8, 9, 6, 7]
     np.testing.assert_array_equal(np.asarray(kp).reshape(-1), [8, 9, 6, 7])
+
+
+@pytest.mark.parametrize("arch,full,cut", [
+    ("gemma2-2b", 2304 ** 0.5, 128 ** 0.5),   # sqrt(d_model) follows d
+    ("granite-4.0-h-small", 12.0, 12.0),       # a fixed multiplier stays
+    ("mistral-large-123b", None, None),        # no multiplier
+])
+def test_reduced_keeps_the_embedding_multiplier_rule(arch, full, cut):
+    cfg = configs.get(arch)
+    assert cfg.emb_mult == full
+    assert reduced(cfg).emb_mult == cut
