@@ -291,28 +291,34 @@ def packed_flash_decode(q, k_packed: Packed, v_packed: Packed, pos, *,
 
 
 def paged_flash_decode(q, k_packed: Packed, v_packed: Packed,
-                       tables, pos, *, fields: PackFields, softcap=None,
+                       tables, pos, layer, *, fields: PackFields,
+                       softcap=None,
                        prefix_planes: Optional[int] = None) -> jax.Array:
-    """One-token decode attention over a paged SFP-packed KV block pool.
+    """One-token decode attention over one layer of a paged SFP-packed KV
+    block pool.
 
-    The continuous-batching serving step: pool parts are
-    (P_blocks, block_l, D) shared across requests, ``tables`` (B, nb)
-    maps each row's logical blocks to physical pool blocks, and ``pos``
-    (B,) carries per-row decode positions. On pallas/interpret the block
-    table is a scalar-prefetch operand and the gather happens inside the
-    kernel grid (no contiguous per-request cache in HBM); on the ref
-    backend this is the gather-unpack-attend oracle with the identical
-    block recurrence. Global attention only. ``prefix_planes`` is the
-    speculative draft read mode (see ``packed_flash_decode``).
+    The continuous-batching serving step: pool parts are stacked over
+    layers, (n_layers, P_blocks, block_l, D), shared across requests, and
+    ``layer`` picks the one attended; ``tables`` (B, nb) maps each row's
+    logical blocks to physical pool blocks, and ``pos`` (B,) carries
+    per-row decode positions. On pallas/interpret the block table and the
+    layer are scalar-prefetch operands and the gather happens inside the
+    kernel grid (neither the layer's slice nor a contiguous per-request
+    cache in HBM); on the ref backend this is the gather-unpack-attend
+    oracle on the layer's slice, with the identical block recurrence.
+    Global attention only. ``prefix_planes`` is the speculative draft read
+    mode (see ``packed_flash_decode``).
     """
     b = backend()
     if b in ("pallas", "interpret"):
         return _pfd.paged_flash_decode(
             q, k_packed.payload, k_packed.bases, v_packed.payload,
             v_packed.bases, jnp.asarray(tables, jnp.int32),
-            jnp.asarray(pos, jnp.int32), fields=fields, softcap=softcap,
-            interpret=(b == "interpret"), prefix_planes=prefix_planes)
+            jnp.asarray(pos, jnp.int32), jnp.asarray(layer, jnp.int32),
+            fields=fields, softcap=softcap, interpret=(b == "interpret"),
+            prefix_planes=prefix_planes)
+    k, v = (Packed(payload=p.payload[layer], bases=p.bases[layer])
+            for p in (k_packed, v_packed))
     return _ref.paged_flash_decode(
-        q, k_packed.payload, k_packed.bases, v_packed.payload,
-        v_packed.bases, tables, pos, fields, softcap=softcap,
-        prefix_planes=prefix_planes)
+        q, k.payload, k.bases, v.payload, v.bases, tables, pos, fields,
+        softcap=softcap, prefix_planes=prefix_planes)

@@ -25,13 +25,14 @@ decode position (scalar, or one per batch row — continuous-batching
 slots) via ``ref.decode_kv_mask``.
 
 ``paged_flash_decode`` is the continuous-batching variant: KV blocks live
-in a request-agnostic pool and each row's logical blocks are gathered
-through its block table *inside the grid* — the table is a scalar-prefetch
-operand consumed by the BlockSpec index_maps, so each (row, block) step
-DMAs its physical block straight from the HBM pool. Same recurrence, same
-bit machine, same masks; the logical blocks past each row's position are
-skipped (neither fetched nor expanded), so a row's cost follows its
-length rather than the table's.
+in a request-agnostic pool, stacked over layers, and each row's logical
+blocks are gathered through its block table *inside the grid* — the table
+and the layer index are scalar-prefetch operands consumed by the BlockSpec
+index_maps, so each (row, block) step DMAs its physical block of that
+layer straight from the HBM pool. Same recurrence, same bit machine, same
+masks; the logical blocks past each row's position are skipped (neither
+fetched nor expanded), so a row's cost follows its length rather than the
+table's.
 
 Oracles: ``ref.packed_flash_decode`` / ``ref.paged_flash_decode``
 (unpack-then-attend with the same block recurrence) — bit-exact against
@@ -231,18 +232,20 @@ def packed_flash_decode(q: jax.Array, k_payload: jax.Array,
     return out.reshape(B, 1, H, hd)
 
 
-def _paged_kernel(tab_ref, pos_ref, q_ref, kp_ref, kb_ref, vp_ref, vb_ref,
-                  o_ref, m_scr, l_scr, acc_scr, *, block_l: int, nb: int,
-                  KH: int, hd: int, softcap: Optional[float], scale: float,
+def _paged_kernel(tab_ref, pos_ref, layer_ref, q_ref, kp_ref, kb_ref,
+                  vp_ref, vb_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                  block_l: int, nb: int, KH: int, hd: int,
+                  softcap: Optional[float], scale: float,
                   fields: kref.PackFields, spec,
                   prefix_planes: Optional[int] = None):
     """One (batch row, logical KV block) step over the paged pool.
 
     The DMA gather already happened: the grid spec's index_map routed this
-    step's physical block (``tab_ref[b, j]``) into kp/kb/vp/vb, so the body
-    is the contiguous decode kernel's on logical slots — the recurrence,
-    masking and bit machine are shared, which is what makes paged decode
-    bit-exact against the contiguous kernel over the same logical cache.
+    step's physical block (``tab_ref[b, j]`` of layer ``layer_ref[0]``)
+    into kp/kb/vp/vb, so the body is the contiguous decode kernel's on
+    logical slots — the recurrence, masking and bit machine are shared,
+    which is what makes paged decode bit-exact against the contiguous
+    kernel over the same logical cache.
 
     Logical blocks past the row's position (``ki * block_l > pos``) are
     skipped: the index_map re-points them at the row's last live block
@@ -308,37 +311,44 @@ def _paged_kernel(tab_ref, pos_ref, q_ref, kp_ref, kb_ref, vp_ref, vb_ref,
 def paged_flash_decode(q: jax.Array, k_payload: jax.Array,
                        k_bases: jax.Array, v_payload: jax.Array,
                        v_bases: jax.Array, tables: jax.Array,
-                       pos: jax.Array, *, fields: kref.PackFields,
+                       pos: jax.Array, layer: jax.Array, *,
+                       fields: kref.PackFields,
                        softcap: Optional[float] = None,
                        interpret: Optional[bool] = None,
                        prefix_planes: Optional[int] = None) -> jax.Array:
-    """One-token attention over a *paged* SFP-packed KV block pool.
+    """One-token attention over one layer of a *paged* SFP-packed KV pool.
 
     The serving engine's continuous-batching decode step: pool parts are
-    (P_blocks, block_l, D) payload / (P_blocks, block_l, D // 128) bases
-    shared by every request; ``tables`` (B, nb) int32 maps each batch
-    row's logical KV blocks to physical pool blocks, and ``pos`` (B,) is
-    each row's absolute decode position. The block table is a scalar-
-    prefetch operand, so the *gather happens inside the kernel grid*: each
-    (b, j) step's index_map DMAs physical block ``tables[b, j]`` straight
-    from the HBM pool into VMEM — no contiguous per-request cache ever
-    materializes. Logical blocks past a row's position are skipped:
-    their steps re-point at the row's last live block, so they are
-    neither fetched nor expanded, and the output is bit-identical to
-    attending them masked. Their table entries must still be valid
-    physical indices (the reserved trash block is one). Global attention
-    only (local ring buffers are window-bounded and stay per-slot
-    contiguous). Returns (B, 1, H, hd) in q's dtype.
+    stacked over layers, (n_layers, P_blocks, block_l, D) payload /
+    (n_layers, P_blocks, block_l, D // 128) bases shared by every request,
+    and ``layer`` (an int32 scalar) picks the layer this call attends.
+    ``tables`` (B, nb) int32 maps each batch row's logical KV blocks to
+    physical pool blocks, and ``pos`` (B,) is each row's absolute decode
+    position. The block table and the layer are scalar-prefetch operands,
+    so the *gather happens inside the kernel grid*: each (b, j) step's
+    index_map DMAs block ``tables[b, j]`` of layer ``layer`` straight from
+    the HBM pool into VMEM — neither the layer's slice of the pool nor a
+    contiguous per-request cache ever materializes, which is what lets a
+    layer scan carry the stacked pool and update it in place. A pool of
+    one layer is passed as ``part[None]`` with ``layer`` 0 (a bitcast).
+    Logical blocks past a row's position are skipped: their steps re-point
+    at the row's last live block, so they are neither fetched nor
+    expanded, and the output is bit-identical to attending them masked.
+    Their table entries must still be valid physical indices (the reserved
+    trash block is one). Global attention only (local ring buffers are
+    window-bounded and stay per-slot contiguous). Returns (B, 1, H, hd) in
+    q's dtype.
 
-    Oracle: ``ref.paged_flash_decode`` — bit-exact in interpret mode,
-    equal to f32 rounding when compiled for a TPU.
+    Oracle: ``ref.paged_flash_decode`` on the layer's slice — bit-exact in
+    interpret mode, equal to f32 rounding when compiled for a TPU.
     """
     interpret = kref.default_interpret(interpret)
 
     B, one, H, hd = q.shape
     assert one == 1, q.shape
-    n_phys, block_l, Dp = k_payload.shape
-    G = k_bases.shape[2]
+    assert k_payload.ndim == 4, k_payload.shape  # (layers, P, block_l, Dp)
+    block_l, Dp = k_payload.shape[2:]
+    G = k_bases.shape[3]
     D = G * kref.GROUP
     KH = D // hd
     assert KH * hd == D, (D, hd)
@@ -350,27 +360,29 @@ def paged_flash_decode(q: jax.Array, k_payload: jax.Array,
 
     qg = q.reshape(B, KH, rep, hd)
     pos1 = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
+    layer1 = jnp.asarray(layer, jnp.int32).reshape(1)
     tables = tables.astype(jnp.int32)
     scale = 1.0 / (hd ** 0.5)
 
-    def kv_block(b, j, tab, pos):
+    def kv_block(b, j, tab, pos, layer):
         # Steps past the row's last live block repeat its index, so the
         # pipeline issues no copy for them (the body skips them too).
-        return (tab[b, jnp.minimum(j, pos[b] // block_l)], 0, 0)
+        return (layer[0], tab[b, jnp.minimum(j, pos[b] // block_l)], 0, 0)
+
+    def row(b, j, tab, pos, layer):
+        return (b, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # (tables, pos) — available to index_maps
+        num_scalar_prefetch=3,  # (tables, pos, layer) — for the index_maps
         grid=(B, nb),
         in_specs=[
-            pl.BlockSpec((1, KH, rep, hd),
-                         lambda b, j, tab, pos: (b, 0, 0, 0)),
-            pl.BlockSpec((1, block_l, Dp), kv_block),
-            pl.BlockSpec((1, block_l, G), kv_block),
-            pl.BlockSpec((1, block_l, Dp), kv_block),
-            pl.BlockSpec((1, block_l, G), kv_block),
+            pl.BlockSpec((1, KH, rep, hd), row),
+            pl.BlockSpec((None, 1, block_l, Dp), kv_block),
+            pl.BlockSpec((None, 1, block_l, G), kv_block),
+            pl.BlockSpec((None, 1, block_l, Dp), kv_block),
+            pl.BlockSpec((None, 1, block_l, G), kv_block),
         ],
-        out_specs=pl.BlockSpec((1, KH, rep, hd),
-                               lambda b, j, tab, pos: (b, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, KH, rep, hd), row),
         scratch_shapes=[
             _vmem_scratch((KH, rep, 1)),
             _vmem_scratch((KH, rep, 1)),
@@ -385,5 +397,5 @@ def paged_flash_decode(q: jax.Array, k_payload: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KH, rep, hd), q.dtype),
         interpret=interpret,
-    )(tables, pos1, qg, k_payload, k_bases, v_payload, v_bases)
+    )(tables, pos1, layer1, qg, k_payload, k_bases, v_payload, v_bases)
     return out.reshape(B, 1, H, hd)

@@ -71,6 +71,16 @@ def _kvcache():
     return kvcache
 
 
+def _split_pools(caches):
+    """({slot: paged pool}, {slot: any other cache} or None) of one
+    group of layer caches (None without a cache: prefill, training)."""
+    if caches is None:
+        return {}, None
+    paged = _kvcache().PagedKV
+    pools = {k: c for k, c in caches.items() if isinstance(c, paged)}
+    return pools, {k: c for k, c in caches.items() if k not in pools}
+
+
 class DecoderModel:
     def __init__(self, cfg: ArchConfig, policy=None, mesh=None,
                  rules=None, kv_container: Optional[str] = None,
@@ -481,10 +491,11 @@ class DecoderModel:
         hn = common.rmsnorm(slot_params["pre_norm"], h, cfg.norm_eps)
         if kind in (GLOBAL, LOCAL):
             if tables is not None and kind == GLOBAL:
-                # Paged pool: blocks gathered through the block table
-                # inside the kernel; local ring layers stay per-slot
-                # contiguous (window-bounded) and take the packed path
-                # below with per-row positions.
+                # Paged pool (slot_cache a kvcache.PoolLayer, new_cache
+                # the updated stacked pool): blocks gathered through the
+                # block table inside the kernel; local ring layers stay
+                # per-slot contiguous (window-bounded) and take the
+                # packed path below with per-row positions.
                 out, new_cache = _kvcache().attention_decode_paged(
                     slot_params["attn"], hn, slot_cache, tables, pos, cfg,
                     container=self.kv_container,
@@ -556,32 +567,54 @@ class DecoderModel:
         """Run ``slot_fn(slot_params, h, slot_cache, kind) -> (h, cache,
         stats)`` over every layer: the periods as one scan, then the
         remainder. Returns (h, caches, stats), stats being each dropless
-        MoE counter stacked over the MoE layers in order ({} without)."""
+        MoE counter stacked over the MoE layers in order ({} without).
+
+        A paged block pool in ``cache`` (``kvcache.PagedKV``, stacked over
+        the periods) rides the scan's carry rather than its xs/ys: its
+        layer gets ``kvcache.PoolLayer(pool, period index)`` and returns
+        the updated stacked pool, so a decode step writes and reads the
+        one (donated) buffer in place instead of slicing each layer's pool
+        out and stacking it back. A remainder layer's pool is a stack of
+        one."""
         cfg = self.cfg
 
-        def run(h, p, c, kinds):
+        def run(h, p, c, kinds, pools, layer):
             caches, stats = {}, []
             for i, kind in enumerate(kinds):
-                h, caches[f"slot{i}"], st = slot_fn(
-                    p[f"slot{i}"], h, None if c is None else c[f"slot{i}"],
-                    kind)
+                key = f"slot{i}"
+                if key in pools:
+                    view = _kvcache().PoolLayer(pools[key], layer)
+                    h, pools[key], st = slot_fn(p[key], h, view, kind)
+                else:
+                    h, caches[key], st = slot_fn(
+                        p[key], h, None if c is None else c[key], kind)
                 if st is not None:
                     stats.append(st)
-            return h, caches, {k: jnp.stack([st[k] for st in stats])
-                               for k in (stats[0] if stats else {})}
+            return h, caches, pools, {k: jnp.stack([st[k] for st in stats])
+                                      for k in (stats[0] if stats else {})}
 
-        def period_fn(h, x):
-            h, caches, stats = run(h, x[0], x[1], cfg.period)
-            return h, (caches, stats)
+        def period_fn(carry, x):
+            h, pools = carry
+            p, c, layer = x
+            h, caches, pools, stats = run(h, p, c, cfg.period, dict(pools),
+                                          layer)
+            return (h, pools), (caches, stats)
 
-        xs = (params["periods"], None if cache is None else cache["periods"])
-        h, (period_caches, stats) = jax.lax.scan(period_fn, h, xs)
+        pools, rest = _split_pools(None if cache is None
+                                   else cache["periods"])
+        xs = (params["periods"], rest,
+              jnp.arange(cfg.n_periods) if pools else None)
+        (h, pools), (period_caches, stats) = jax.lax.scan(period_fn,
+                                                          (h, pools), xs)
         stats = {k: v.reshape(-1) for k, v in stats.items()}
-        out = {"periods": period_caches}
+        out = {"periods": {**period_caches, **pools}}
         if cfg.remainder:
-            h, out["rem"], rem_stats = run(
-                h, params["rem"], None if cache is None else cache["rem"],
-                cfg.remainder)
+            pools, rest = _split_pools(None if cache is None
+                                       else cache["rem"])
+            pools = jax.tree.map(lambda a: a[None], pools)
+            h, rem, pools, rem_stats = run(h, params["rem"], rest,
+                                           cfg.remainder, pools, 0)
+            out["rem"] = {**rem, **jax.tree.map(lambda a: a[0], pools)}
             stats = {k: jnp.concatenate([stats[k], rem_stats[k]])
                      for k in stats}
         return h, out, stats
@@ -626,8 +659,9 @@ class DecoderModel:
         step: ``pos`` becomes (B,) per-slot positions (idle slots carry
         pos 0 and a trash-block table row; their logits are garbage the
         engine discards), global attention layers in ``cache`` hold
-        ``kvcache.PagedKV`` pool slices addressed through the tables, and
-        local ring / SSD / RGLRU layers hold per-slot dense state.
+        ``kvcache.PagedKV`` block pools (stacked over the periods, carried
+        through the layer scan) addressed through the tables, and local
+        ring / SSD / RGLRU layers hold per-slot dense state.
         Requires ``kv_container`` in that mode.
 
         ``prefix_planes`` makes every packed-attention *read* expand only

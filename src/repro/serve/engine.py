@@ -653,8 +653,10 @@ class PagedEngine:
         pre-allocates every running slot to its burst horizon), so the
         scan carries only (token, mem) and the per-step host round-trip —
         table upload, dispatch, token download — is paid once per K
-        tokens instead of once per token. The pool memory is donated, so
-        XLA updates the packed blocks in place across all K steps.
+        tokens instead of once per token. The pool memory is donated and
+        rides the carry of this scan and of each step's layer scan, so XLA
+        updates the packed blocks in place across all K steps and layers
+        (``tests/test_tpu_compile.py`` checks the compiled burst).
         """
 
         def burst(params, mem, tables, toks, pos):

@@ -204,21 +204,34 @@ def pack_prefill_cache(cache_kv: attention.KVCache,
 
 
 class PagedKV(NamedTuple):
-    """One global-attention layer's slice of the packed block pool.
+    """The packed block pool of one global-attention layer, or of several
+    stacked on a leading layer axis.
 
     Physical blocks shared by every request: payload
     (P_blocks, block_l, fields.nd_payload_cols(D)) — 8/16-bit words, or
     uint8 bit planes for dense sub-byte geometries — and bases
     (P_blocks, block_l, D // 128) uint8 in the ``sfp_pack_nd`` /
-    ``bitplane_pack_nd`` layout. Which blocks belong to which request
-    lives outside, in the engine's block tables — the pool itself is
-    request-agnostic, which is what lets freed blocks recycle instantly.
+    ``bitplane_pack_nd`` layout; the engine stacks the scanned layers'
+    pools as (n_periods, P_blocks, ...). Which blocks belong to which
+    request lives outside, in the engine's block tables — the pool itself
+    is request-agnostic, which is what lets freed blocks recycle instantly.
     """
 
     k_payload: jax.Array
     k_bases: jax.Array
     v_payload: jax.Array
     v_bases: jax.Array
+
+
+class PoolLayer(NamedTuple):
+    """One global-attention layer's place in a stacked pool: ``pool``
+    (``PagedKV`` parts (n_layers, P_blocks, block_l, cols)) and ``layer``,
+    an int32 index on its leading axis. A decode step's layer scan carries
+    the stacked pool and hands each layer this view, so the layer writes
+    and reads its blocks in the one buffer instead of a slice of it."""
+
+    pool: PagedKV
+    layer: jax.Array
 
 
 def _paged_fields(cfg: ArchConfig, container: Optional[str]):
@@ -297,7 +310,7 @@ def paged_block_checksums(paged: PagedKV, salt: int = 0) -> jax.Array:
     return total
 
 
-def attention_decode_paged(params, h_tok: jax.Array, paged: PagedKV,
+def attention_decode_paged(params, h_tok: jax.Array, paged: PoolLayer,
                            tables: jax.Array, pos: jax.Array,
                            cfg: ArchConfig, *,
                            container: Optional[str] = None,
@@ -305,15 +318,18 @@ def attention_decode_paged(params, h_tok: jax.Array, paged: PagedKV,
                            ) -> Tuple[jax.Array, PagedKV]:
     """One continuous-batching decode step over the paged block pool.
 
-    ``tables`` (B, nb) int32 maps each batch row's logical KV blocks to
-    physical pool blocks; ``pos`` (B,) is each row's absolute decode
-    position. The new token's K/V row is packed and scattered into the
-    row's current block (idle rows must point at the reserved trash
-    block), then attention reads the pool directly through the paged
-    flash-decode kernel — the gather happens inside the kernel grid via
-    the scalar-prefetched block table. Global attention only (local ring
-    buffers are window-bounded and stay per-slot contiguous). The pool is
-    a single-host structure; multi-host pool sharding is future work.
+    ``paged`` is the layer's place in the stacked pool; the updated
+    stacked pool is returned. ``tables`` (B, nb) int32 maps each batch
+    row's logical KV blocks to physical pool blocks; ``pos`` (B,) is each
+    row's absolute decode position. The new token's K/V row is packed and
+    scattered into the row's current block of this layer (idle rows must
+    point at the reserved trash block) — inside a layer scan that carries
+    the donated pool, XLA does this in place — then attention reads the
+    pool directly through the paged flash-decode kernel, the gather
+    happening inside the kernel grid via the scalar-prefetched block table
+    and layer. Global attention only (local ring buffers are
+    window-bounded and stay per-slot contiguous). The pool is a
+    single-host structure; multi-host pool sharding is future work.
     ``prefix_planes`` (speculative draft steps) expands only the leading
     P' payload bits on the read side; writes stay full width.
     """
@@ -321,7 +337,8 @@ def attention_decode_paged(params, h_tok: jax.Array, paged: PagedKV,
     B = h_tok.shape[0]
     hd, H, KH = cfg.head_dim_, cfg.n_heads, cfg.n_kv_heads
     D = KH * hd
-    block_l = paged.k_payload.shape[1]
+    pool, layer = paged
+    block_l = pool.k_payload.shape[2]
     dtype = h_tok.dtype
     fields = codec.pack_fields(dtype)
     assert fields is not None, codec.name
@@ -336,19 +353,18 @@ def attention_decode_paged(params, h_tok: jax.Array, paged: PagedKV,
     rows = jnp.arange(B)
     phys = tables[rows, pos // block_l]
     off = pos % block_l
-    paged = PagedKV(
-        k_payload=paged.k_payload.at[phys, off].set(
-            k_pt.data["payload"][:, 0]),
-        k_bases=paged.k_bases.at[phys, off].set(k_pt.data["bases"][:, 0]),
-        v_payload=paged.v_payload.at[phys, off].set(
-            v_pt.data["payload"][:, 0]),
-        v_bases=paged.v_bases.at[phys, off].set(v_pt.data["bases"][:, 0]))
+    at = (layer, phys, off)
+    pool = PagedKV(
+        k_payload=pool.k_payload.at[at].set(k_pt.data["payload"][:, 0]),
+        k_bases=pool.k_bases.at[at].set(k_pt.data["bases"][:, 0]),
+        v_payload=pool.v_payload.at[at].set(v_pt.data["payload"][:, 0]),
+        v_bases=pool.v_bases.at[at].set(v_pt.data["bases"][:, 0]))
 
     o = ops.paged_flash_decode(
         q.astype(dtype),
-        ops.Packed(payload=paged.k_payload, bases=paged.k_bases),
-        ops.Packed(payload=paged.v_payload, bases=paged.v_bases),
-        tables, pos, fields=fields, softcap=cfg.attn_softcap,
+        ops.Packed(payload=pool.k_payload, bases=pool.k_bases),
+        ops.Packed(payload=pool.v_payload, bases=pool.v_bases),
+        tables, pos, layer, fields=fields, softcap=cfg.attn_softcap,
         prefix_planes=prefix_planes)
     out = o.reshape(B, 1, H * hd) @ params["wo"]
-    return out, paged
+    return out, pool

@@ -4,9 +4,9 @@ The serving analogue of the paper's containers-at-the-memory-interface: KV
 bytes live *packed* in fixed-size physical blocks (one block = the packed
 flash-decode kernel's KV block — ``ops.DECODE_BLOCK_L`` token rows), and a
 request owns blocks, not a contiguous region. Device memory is one
-request-agnostic pool slice per global-attention layer
-(``kvcache.PagedKV``); this module owns everything host-side: the free
-list, per-slot block tables, admission accounting, eviction, and the
+request-agnostic pool per global-attention layer (``kvcache.PagedKV``,
+the scanned layers' stacked); this module owns everything host-side: the
+free list, per-slot block tables, admission accounting, eviction, and the
 block *quarantine* (integrity-failed blocks held out of circulation until
 scrubbed — see serve/faults.py and the scheduler's recovery path).
 

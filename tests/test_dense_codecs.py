@@ -227,8 +227,9 @@ def test_dense_paged_decode_bit_exact_sub_byte():
     tables = jnp.asarray([[1, 2], [3, 0]], jnp.int32)  # row 1: trash tail
     posv = jnp.asarray([2 * bl - 1, bl - 6], jnp.int32)
     q = _x((B, 1, H, hd), dtype, seed=6)
-    got = pfd.paged_flash_decode(q, kp_p, kb_p, vp_p, vb_p, tables, posv,
-                                 fields=f, interpret=True)
+    got = pfd.paged_flash_decode(q, kp_p[None], kb_p[None], vp_p[None],
+                                 vb_p[None], tables, posv, 0, fields=f,
+                                 interpret=True)
     oracle = jax.jit(functools.partial(ref.paged_flash_decode, fields=f))
     want = oracle(q, kp_p, kb_p, vp_p, vb_p, tables, posv)
     np.testing.assert_array_equal(np.asarray(got, np.float32),

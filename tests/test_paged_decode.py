@@ -31,27 +31,40 @@ def _pool(key, n_phys, bl, D, container, dtype):
     return (kp, kb, vp, vb), f
 
 
+@pytest.mark.parametrize("draft", [False, True],
+                         ids=["full", "prefix_planes"])
 @pytest.mark.parametrize("container,dtype", [("sfp8", jnp.bfloat16),
+                                             ("sfp-m2e4", jnp.bfloat16),
                                              ("sfp16", jnp.float32)])
 @pytest.mark.parametrize("rep", [1, 4])  # GQA ratio H / KH
-def test_paged_kernel_bit_exact_vs_oracle(container, dtype, rep):
-    B, KH, hd, bl, nb, n_phys = 3, 2, 64, 16, 3, 8
+def test_paged_kernel_bit_exact_vs_oracle(container, dtype, rep, draft):
+    """The kernel reads a pool stacked over layers at the ``layer`` it is
+    given: at each layer it must equal, bit for bit, the oracle on that
+    layer's slice alone."""
+    B, KH, hd, bl, nb, n_phys, n_layers = 4, 2, 64, 16, 3, 8, 3
     H = KH * rep
-    packed, f = _pool(jax.random.PRNGKey(0), n_phys, bl, KH * hd,
-                      container, dtype)
+    layers = [_pool(jax.random.PRNGKey(11 + i), n_phys, bl, KH * hd,
+                    container, dtype) for i in range(n_layers)]
+    f = layers[0][1]
+    stacked = [jnp.stack(part) for part in zip(*(p for p, _ in layers))]
     q = jax.random.normal(jax.random.PRNGKey(1), (B, 1, H, hd),
                           jnp.float32).astype(dtype)
-    # Rows at different fill levels; row 1 has unallocated logical blocks
-    # pointing at the trash block (0) — masked by position.
-    tables = jnp.array([[1, 4, 2], [7, 0, 0], [5, 3, 6]], jnp.int32)
-    pos = jnp.array([40, 9, 33], jnp.int32)
-    got = pfd.paged_flash_decode(q, *packed, tables, pos, fields=f,
-                                 softcap=30.0, interpret=True)
+    # Rows at pos 0, block_l - 1, block_l and L - 1; the unallocated
+    # logical blocks point at the trash block (0) — masked by position.
+    tables = jnp.array([[1, 0, 0], [7, 0, 0], [5, 3, 0], [2, 4, 6]],
+                       jnp.int32)
+    pos = jnp.array([0, bl - 1, bl, nb * bl - 1], jnp.int32)
+    planes = (max(f.payload_bits - 1, f.dexp_bits + 2) if draft else None)
     oracle = jax.jit(functools.partial(ref.paged_flash_decode, fields=f,
-                                       softcap=30.0))
-    want = oracle(q, *packed, tables, pos)
-    np.testing.assert_array_equal(np.asarray(got, np.float32),
-                                  np.asarray(want, np.float32))
+                                       softcap=30.0, prefix_planes=planes))
+    for layer, (packed, _) in enumerate(layers):
+        got = pfd.paged_flash_decode(q, *stacked, tables, pos,
+                                     jnp.int32(layer), fields=f,
+                                     softcap=30.0, interpret=True,
+                                     prefix_planes=planes)
+        want = oracle(q, *packed, tables, pos)
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
 
 
 def test_paged_matches_contiguous_on_same_logical_cache():
@@ -66,8 +79,8 @@ def test_paged_matches_contiguous_on_same_logical_cache():
     q = jax.random.normal(jax.random.PRNGKey(3), (B, 1, H, hd), dtype)
     pos = jnp.array([bl * nb - 1, 17], jnp.int32)
     ident = jnp.broadcast_to(jnp.arange(nb, dtype=jnp.int32), (B, nb))
-    got = pfd.paged_flash_decode(q, kp, kb, vp, vb, ident, pos, fields=f,
-                                 interpret=True)
+    got = pfd.paged_flash_decode(q, kp[None], kb[None], vp[None], vb[None],
+                                 ident, pos, 0, fields=f, interpret=True)
     want = pfd.packed_flash_decode(
         q, jnp.broadcast_to(kp.reshape(1, nb * bl, D), (B, nb * bl, D)),
         jnp.broadcast_to(kb.reshape(1, nb * bl, D // 128),
@@ -106,11 +119,13 @@ def test_contiguous_kernel_vector_pos_matches_per_row(window):
 
 
 def test_ops_paged_dispatch_ref_vs_interpret():
-    """ops.paged_flash_decode: ref oracle and interpret kernel agree."""
+    """ops.paged_flash_decode: ref oracle and interpret kernel agree, on
+    the second layer of a pool stacked over two."""
     B, KH, hd, bl, n_phys = 2, 2, 64, 16, 6
     dtype = jnp.float32
     (kp, kb, vp, vb), f = _pool(jax.random.PRNGKey(7), n_phys, bl, KH * hd,
                                 "sfp8", dtype)
+    kp, kb, vp, vb = (jnp.stack([a[::-1], a]) for a in (kp, kb, vp, vb))
     q = jax.random.normal(jax.random.PRNGKey(8), (B, 1, KH, hd), dtype)
     tables = jnp.array([[2, 5], [4, 0]], jnp.int32)
     pos = jnp.array([25, 3], jnp.int32)
@@ -120,7 +135,7 @@ def test_ops_paged_dispatch_ref_vs_interpret():
         try:
             outs[backend] = np.asarray(ops.paged_flash_decode(
                 q, ops.Packed(payload=kp, bases=kb),
-                ops.Packed(payload=vp, bases=vb), tables, pos, fields=f),
+                ops.Packed(payload=vp, bases=vb), tables, pos, 1, fields=f),
                 np.float32)
         finally:
             ops.force_backend(None)
@@ -171,9 +186,10 @@ def test_trailing_trash_blocks_are_exact_noops(dead, container, dtype,
     planes = (max(f.payload_bits - 1, f.dexp_bits + 2) if draft else None)
     run = functools.partial(pfd.paged_flash_decode, fields=f, softcap=30.0,
                             interpret=True, prefix_planes=planes)
-    want = run(q, kp, kb, vp, vb, table(nb, 0), pos)
-    got = run(q, kp, kb, vp, vb,
-              table(nb + 2, poison if dead == "poison" else 0), pos)
+    pool = (kp[None], kb[None], vp[None], vb[None])
+    want = run(q, *pool, table(nb, 0), pos, 0)
+    got = run(q, *pool, table(nb + 2, poison if dead == "poison" else 0),
+              pos, 0)
     assert np.isfinite(np.asarray(want, np.float32)).all()
     np.testing.assert_array_equal(np.asarray(got, np.float32),
                                   np.asarray(want, np.float32))

@@ -3,6 +3,7 @@
 contiguous token equivalence under continuous batching, and policy-aware
 container resolution from checkpoint metadata."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -10,7 +11,7 @@ import numpy as np
 import pytest
 
 from repro import codecs, configs
-from repro.configs.base import reduced
+from repro.configs.base import GLOBAL, reduced
 from repro.kernels import ops
 from repro.models.model import DecoderModel
 from repro.serve import engine, kvcache, precision
@@ -154,6 +155,31 @@ def test_scheduler_matches_generate_gqa4():
     assert cfg.n_heads // cfg.n_kv_heads == 4
     params = model.init(jax.random.PRNGKey(0))
     rng = np.random.RandomState(7)
+    reqs = [Request(uid=i, prompt=p, max_new=3)
+            for i, p in enumerate(_prompts(rng, cfg, [5, 8]))]
+    ops.force_backend("interpret")
+    try:
+        eng = engine.PagedEngine(model, params, max_slots=2, max_len=128)
+        out = Scheduler(eng).run(reqs)
+        for r in reqs:
+            want = engine.generate(model, params,
+                                   jnp.asarray(r.prompt)[None],
+                                   max_new=r.max_new, max_len=eng.max_len)
+            np.testing.assert_array_equal(out[r.uid],
+                                          np.asarray(want.tokens[0]))
+    finally:
+        ops.force_backend(None)
+
+
+def test_scheduler_matches_generate_global_remainder():
+    """A global layer past the last whole period keeps its own one-layer
+    pool outside the layer scan (read as a stack of one): tokens through
+    the whole engine must equal per-request generate."""
+    cfg, model = _model("mistral-large-123b", "sfp8",
+                        period=(GLOBAL, GLOBAL), n_layers=3)
+    assert cfg.n_periods == 1 and cfg.remainder == (GLOBAL,)
+    params = model.init(jax.random.PRNGKey(0))
+    rng = np.random.RandomState(19)
     reqs = [Request(uid=i, prompt=p, max_new=3)
             for i, p in enumerate(_prompts(rng, cfg, [5, 8]))]
     ops.force_backend("interpret")
@@ -598,6 +624,66 @@ def test_burst_matches_generate_interpret():
                                    max_new=r.max_new, max_len=eng.max_len)
             np.testing.assert_array_equal(out[r.uid],
                                           np.asarray(want.tokens[0]))
+    finally:
+        ops.force_backend(None)
+
+
+def _sliced_step(model, params, mem, tables, toks, pos):
+    """A paged decode step of a model of global layers with the pool as
+    the layer scan's xs and ys: each layer's pool sliced out of the stack,
+    run as a pool of one layer, and stacked back. Returns (next tokens,
+    mem)."""
+    def layer(h, x):
+        p, pool = x
+        view = kvcache.PoolLayer(jax.tree.map(lambda a: a[None], pool), 0)
+        h, pool, _ = model._decode_slot(p["slot0"], h, view, pos, GLOBAL,
+                                        tables=tables)
+        return h, jax.tree.map(lambda a: a[0], pool)
+
+    assert model.cfg.period == (GLOBAL,) and not model.cfg.remainder
+    h, pool = jax.lax.scan(layer, model._embed(params, toks),
+                           (params["periods"], mem["periods"]["slot0"]))
+    logits = model._logits(params, h)
+    nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
+    return nxt, {"periods": {"slot0": pool}}
+
+
+@pytest.mark.parametrize("burst", [1, 2])
+def test_carried_pool_matches_pool_sliced_per_layer(burst):
+    """The engine's step carries the stacked pool through the layer scan
+    and addresses it by layer index. Over the interpret kernels, its
+    tokens and its whole new memory must equal, bit for bit, the same
+    steps run with each layer's pool sliced out and stacked back: three
+    single steps, or a burst of 2. One slot crosses a block boundary, one
+    is idle on the trash block."""
+    cfg, model = _model("mistral-large-123b", "sfp-m2e4")
+    params = model.init(jax.random.PRNGKey(0))
+    rng = np.random.RandomState(17)
+    prompts = _prompts(rng, cfg, [126, 7])
+    ops.force_backend("interpret")
+    try:
+        eng = engine.PagedEngine(model, params, max_slots=3, max_len=256)
+        toks, pos = np.zeros(3, np.int32), np.zeros(3, np.int32)
+        for slot, p in enumerate(prompts):
+            assert eng.pool.alloc_upto(slot, p.size + 3)
+            toks[slot], pos[slot] = eng.prefill_into_slot(slot, p), p.size
+        tables = jnp.asarray(eng.pool.tables)
+        sliced = jax.jit(functools.partial(_sliced_step, model))
+        mem = jax.tree.map(jnp.copy, eng.mem)
+        cur, want = toks, []
+        for i in range(3 if burst == 1 else burst):
+            nxt, mem = sliced(params, mem, tables, jnp.asarray(cur)[:, None],
+                              jnp.asarray(pos + i))
+            if burst == 1:
+                got, _ = eng.decode(cur, pos + i)
+                np.testing.assert_array_equal(got, nxt)
+                jax.tree.map(np.testing.assert_array_equal, eng.mem, mem)
+            cur = np.asarray(nxt)
+            want.append(cur)
+        if burst > 1:
+            got, _ = eng.decode_burst(toks, pos, burst)
+            np.testing.assert_array_equal(got, np.stack(want))
+            jax.tree.map(np.testing.assert_array_equal, eng.mem, mem)
     finally:
         ops.force_backend(None)
 
